@@ -10,7 +10,9 @@ aggregate indices into ``artifacts/BENCH_fleet.json``.  Env knobs:
   REPRO_FLEET_CACHE=<dir>   content-addressed result cache: re-runs are free,
                             interrupted streaming sweeps resume per chunk
   REPRO_FLEET_WORKERS=N     dispatch points across N local worker processes
-                            (repro.fleet.dispatch; run.py --workers sets it)
+                            (repro.fleet.dispatch; run.py --workers sets it;
+                            refused above 1 on a TPU host, where one process
+                            holds the chips)
   REPRO_FLEET_LEASE_TTL=S   dispatch lease TTL in seconds (default 30; only
                             a *dead* worker's lease expires — live workers
                             heartbeat-renew — so this is the reclaim delay)
@@ -64,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.chip import check_local_workers, enable_compile_cache
 from repro.configs.base import SwarmConfig
 from repro.fleet import (ProgressWriter, ResultStore, SweepSpec,
                          build_report, execute, publish_spec, run_sweep,
@@ -92,7 +95,9 @@ def default_store(required: bool = False) -> Optional[ResultStore]:
 
 
 def default_workers() -> int:
-    return int(os.environ.get("REPRO_FLEET_WORKERS", "1"))
+    workers = int(os.environ.get("REPRO_FLEET_WORKERS", "1"))
+    check_local_workers(workers)
+    return workers
 
 
 def apply_trace_env(spec: SweepSpec) -> SweepSpec:
@@ -140,6 +145,7 @@ def fleet_sweep(spec: SweepSpec, backend: Optional[str] = None,
     contract) routes through ``repro.fleet.dispatch`` — results are
     byte-identical to the single-process path by construction.
     """
+    enable_compile_cache()
     backend = backend or DEFAULT_BACKEND
     workers = default_workers() if workers is None else workers
     spec = apply_trace_env(spec)

@@ -182,7 +182,10 @@ def main(argv=None) -> None:
     if args.watch is not None:
         watch(args.watch)
         return
+    from repro.chip import check_local_workers, enable_compile_cache
+    enable_compile_cache()
     if args.workers is not None:
+        check_local_workers(args.workers)
         # common.fleet_sweep reads the knob at call time, so setting the
         # env here covers every figure sweep below
         os.environ["REPRO_FLEET_WORKERS"] = str(args.workers)

@@ -21,13 +21,15 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, reduced
 from repro.models import build_model
-from repro.launch.mesh import shardings
+from repro.chip import enable_compile_cache
+from repro.launch.mesh import auto_mesh, shardings
 from repro.launch.step import init_train_state, make_train_step, TrainState
 from repro.optim import OptConfig, opt_specs
 from repro.checkpoint import save, restore, latest_step
 from repro.data import DataConfig, batch_at
 
-mesh = jax.make_mesh((n_dev // 2, 2), ("data", "model"))
+enable_compile_cache()
+mesh = auto_mesh((n_dev // 2, 2), ("data", "model"))
 cfg = reduced(get_config("qwen3-1.7b"))
 model = build_model(cfg, mesh=mesh)
 opt = OptConfig(lr=1e-3, warmup_steps=2, total_steps=20)

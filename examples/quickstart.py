@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.chip import enable_compile_cache
 from repro.configs import ARCHS, get_config, reduced
 from repro.data import DataConfig, batch_at
 from repro.launch.step import init_train_state, make_train_step
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--arch", default="qwen3-1.7b", choices=sorted(ARCHS))
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     print(f"arch={args.arch} family={cfg.family} (reduced for CPU)")

@@ -12,12 +12,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.chip import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models import build_model
 from repro.splitcompute import SplitServeEngine, plan_stages
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced(get_config("qwen3-4b"))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

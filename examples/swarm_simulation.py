@@ -16,7 +16,9 @@ N >= 1k regime); all backends are bit-identical (DESIGN.md §8).
 ``--procs N`` goes one level up: the strategy sweep becomes a SweepSpec
 dispatched across N worker *processes* through ``repro.fleet.dispatch``
 (lease-file work stealing over a shared store, DESIGN.md §9) — same
-numbers, point axis parallel.
+numbers, point axis parallel.  A TPU chip belongs to one process, so on a
+TPU host ``--procs`` stays 1 and ``--backend sharded`` spreads the runs
+over the chips.
 
 ``--trace out.json`` additionally runs one per-task-telemetry simulation
 of the Distributed strategy (``repro.trace``, DESIGN.md §10): prints the
@@ -39,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.chip import check_local_workers, enable_compile_cache
 from repro.configs.base import SwarmConfig
 from repro.fleet import (BACKENDS, ResultStore, SweepSpec, dispatch,
                          run_batch)
@@ -94,16 +97,20 @@ def main():
                          "the φ-convergence summary and adds Perfetto "
                          "counter tracks to the timeline")
     args = ap.parse_args()
+    # one process per chip: refuse --procs > 1 on a TPU host up front, and
+    # keep this parent off the backend when it spawns workers
+    check_local_workers(args.procs)
+    enable_compile_cache()
 
-    key = jax.random.PRNGKey(0)
     cfg = dataclasses.replace(SwarmConfig(), num_workers=args.workers,
                               sim_time_s=args.sim_time,
                               mobility_model=args.mobility,
                               channel_model=args.channel,
                               fault_model=args.fault)
+    where = (f"{args.procs} procs" if args.procs > 1
+             else f"{len(jax.devices())} device(s)")
     print(f"{args.workers} UAVs, {args.sim_time:.0f}s, {args.num_runs} runs "
-          f"(backend={args.backend}, {len(jax.devices())} device(s), "
-          f"{args.procs} proc(s)), "
+          f"(backend={args.backend}, {where}), "
           "bursty Markov arrivals (60 ms mean), scenario="
           f"{args.mobility}/{args.channel}/fault:{args.fault}")
 
@@ -117,7 +124,8 @@ def main():
                                      trace_capacity=args.trace_capacity,
                                      trace_hop_capacity=args.trace_hops,
                                      trace_state_every=args.trace_state)
-        m = run_batch(key, cfg_tr, jnp.int32(4), args.workers, 1)
+        m = run_batch(jax.random.PRNGKey(0), cfg_tr, jnp.int32(4),
+                      args.workers, 1)
         dec = decode(np.asarray(m["trace_records"]),
                      np.asarray(m["trace_overflow"]))
         idx = trace_indices(dec)
@@ -188,6 +196,8 @@ def main():
         (pt_ee,) = spec_ee.expand()
         show("Distributed+EE", res_ee[pt_ee.label])
         return
+
+    key = jax.random.PRNGKey(0)
 
     def batch(cfg, s):
         m = run_batch(key, cfg, jnp.int32(s), args.workers, args.num_runs,

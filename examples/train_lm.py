@@ -13,6 +13,7 @@ import time
 
 import jax
 
+from repro.chip import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, batch_at
 from repro.launch.step import init_train_state, make_train_step
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config("qwen3-1.7b"))
     model = build_model(cfg)

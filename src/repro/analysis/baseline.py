@@ -22,52 +22,22 @@ fields, ``field = "Class.field"`` (or ``"function.param"``) plus
 ``reason``.  R002 validates each entry against the live dataclass/function
 and flags stale or shadowed entries, so the table cannot rot.
 
-Parsing: ``tomllib`` when available (Python ≥ 3.11), else a strict
-fallback reader for exactly this shape (table arrays of ``key = "string"``
-pairs) — the file format is kept to that subset on purpose so the suite
-has zero dependencies beyond the repo's own requirements.
+Parsed with the standard library's ``tomllib``.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import re
-from typing import Dict, List, Optional
+import tomllib
+from typing import Dict, Optional
 
 from repro.analysis.astutil import Finding
 
 BASELINE_NAME = "analysis_baseline.toml"
 
-try:
-    import tomllib as _toml
-except ImportError:                                    # Python < 3.11
-    _toml = None
-
+# one `key = "string"` line of an entry (the baseline keeps to that shape)
 _KV = re.compile(r'^([A-Za-z_][A-Za-z0-9_]*)\s*=\s*"((?:[^"\\]|\\.)*)"\s*$')
-
-
-def _parse_subset(text: str) -> Dict[str, List[Dict[str, str]]]:
-    """Fallback parser for the table-array-of-string-pairs TOML subset."""
-    doc: Dict[str, List[Dict[str, str]]] = {}
-    current: Optional[Dict[str, str]] = None
-    for lineno, raw in enumerate(text.splitlines(), 1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("[[") and line.endswith("]]"):
-            name = line[2:-2].strip()
-            current = {}
-            doc.setdefault(name, []).append(current)
-            continue
-        m = _KV.match(line)
-        if m and current is not None:
-            current[m.group(1)] = (m.group(2)
-                                   .replace('\\"', '"').replace("\\\\", "\\"))
-            continue
-        raise ValueError(
-            f"{BASELINE_NAME}:{lineno}: unsupported syntax {line!r} "
-            "(the baseline sticks to [[table]] arrays of key = \"string\")")
-    return doc
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +55,7 @@ class Baseline:
 
 
 def parse_baseline(text: str, path: Optional[str] = None) -> Baseline:
-    doc = (_toml.loads(text) if _toml is not None else _parse_subset(text))
+    doc = tomllib.loads(text)
     allows = []
     for i, entry in enumerate(doc.get("allow", [])):
         missing = {"rule", "file", "symbol", "reason"} - set(entry)

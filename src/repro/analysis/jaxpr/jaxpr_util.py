@@ -11,7 +11,7 @@ actually traces.  Everything here is rule-agnostic plumbing:
   findings share the tier-1 ``Finding`` type and the baseline's
   (rule, file, symbol) matching;
 * :func:`trace32_64` — trace a callable under default x32 *and* under
-  ``jax.experimental.enable_x64`` for the J002 drift comparison.
+  ``jax.enable_x64(True)`` for the J002 drift comparison.
 
 Nothing in this module imports the simulator — target construction lives
 in ``targets.py`` so the walker stays reusable for fixture programs in
@@ -90,7 +90,7 @@ def source_site(eqn) -> Tuple[Optional[str], int, str]:
     frame = None
     if source_info_util is not None:
         try:
-            frame = source_info_util.user_frame(eqn.source_info)
+            frame = source_info_util.user_frame(eqn.source_info.traceback)
         except Exception:                            # pragma: no cover
             frame = None
     if frame is None:
@@ -112,7 +112,6 @@ def trace32_64(fn, *args):
     under x64 is itself a J002 signal (the program's types depend on the
     global flag), so the caller gets the exception instead of a crash.
     """
-    from jax.experimental import enable_x64
     j32 = jax.make_jaxpr(fn)(*args)
     try:
         import warnings
@@ -120,7 +119,7 @@ def trace32_64(fn, *args):
             # promotion FutureWarnings are the *mechanism* J002 reports
             # via avals; don't spam the CLI while retracing
             warnings.simplefilter("ignore")
-            with enable_x64():
+            with jax.enable_x64(True):
                 j64 = jax.make_jaxpr(fn)(*args)
         return j32, j64, None
     except Exception as err:

@@ -153,7 +153,6 @@ def _executor_sharded():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.swarm.simulator import run_sim
     cfg = _sim_cfg()
     devs = np.asarray(jax.devices())
@@ -161,10 +160,11 @@ def _executor_sharded():
     padded = len(devs)
 
     def fn(keys, strategy):
-        return shard_map(
+        return jax.shard_map(
             lambda ks: jax.vmap(
                 lambda k: run_sim(k, cfg, strategy, TARGET_N))(ks),
-            mesh=mesh, in_specs=P("mc"), out_specs=P("mc"))(keys)
+            mesh=mesh, in_specs=P("mc"), out_specs=P("mc"),
+            check_vma=False)(keys)
     keys = jax.random.split(jax.random.PRNGKey(0), padded)
     return fn, (keys, jnp.int32(4))
 

@@ -64,6 +64,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.chip import check_local_workers, enable_compile_cache
 from repro.fleet.executor import BACKENDS, DEFAULT_CHUNK, run_point
 from repro.fleet.store import ResultStore, point_digest
 from repro.fleet.sweep import SweepSpec
@@ -359,8 +360,11 @@ def spawn_workers(spec: SweepSpec, store_root: str, world: int, *,
     """Start ``world`` spawned worker processes over a shared store root.
 
     'spawn' (not fork) so every worker initializes its own JAX runtime —
-    forking a process with a live XLA client deadlocks.
+    forking a process with a live XLA client deadlocks.  On a TPU host
+    only one process can hold the chips, so ``world > 1`` is refused there
+    before anything starts (``repro.chip.check_local_workers``).
     """
+    check_local_workers(world)
     ctx = multiprocessing.get_context("spawn")
     procs = [
         ctx.Process(target=_worker_entry,
@@ -522,6 +526,8 @@ def main(argv=None) -> None:
     ap.add_argument("--progress", default=None,
                     help="progress.jsonl path (benchmarks/run.py --watch)")
     args = ap.parse_args(argv)
+    check_local_workers(args.workers)
+    enable_compile_cache()
 
     store = ResultStore(args.store)
     spec = _load_spec(args.spec, store)

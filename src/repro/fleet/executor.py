@@ -9,11 +9,10 @@ purely operational:
                     default, and exactly the historical ``run_many`` path
                     (``swarm.run_many`` routes here, so the simulator and
                     the benchmarks share this batching code).
-  * ``sharded``   — ``shard_map`` over a 1-D ``("mc",)`` device mesh (built
-                    through ``repro.compat.shard_map``, same shim as
-                    ``models/moe.py``): each device vmaps its slice of the
-                    run axis.  Run count is padded up to the device count by
-                    repeating the last key (padding is computed then
+  * ``sharded``   — ``jax.shard_map`` over a 1-D ``("mc",)`` device mesh:
+                    each device vmaps its slice of the run axis.  Run
+                    count is padded up to the device count by repeating
+                    the last key (padding is computed then
                     discarded — never over-split the key, key-prefix
                     stability does not hold across split widths).
   * ``streaming`` — a host loop over fixed-size chunks; inside a chunk
@@ -60,7 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.configs.base import SwarmConfig
 from repro.fleet.store import ResultStore, code_version, point_digest
 from repro.fleet.sweep import SweepPoint, SweepSpec
@@ -115,9 +113,10 @@ def _profiled_sharded(cfg: SwarmConfig, n: int, padded: int, mesh):
     from jax.sharding import PartitionSpec as P
 
     def fn(keys, strategy):
-        return shard_map(
+        return jax.shard_map(
             lambda ks: jax.vmap(lambda k: run_sim(k, cfg, strategy, n))(ks),
-            mesh=mesh, in_specs=P("mc"), out_specs=P("mc"))(keys)
+            mesh=mesh, in_specs=P("mc"), out_specs=P("mc"),
+            check_vma=False)(keys)
     ks = _key_struct()
     keys_struct = jax.ShapeDtypeStruct((padded,) + ks.shape, ks.dtype)
     t0 = time.perf_counter()

@@ -3,10 +3,10 @@
 
 A sweep point is addressed by the SHA-256 of everything that determines its
 numbers: the full ``SwarmConfig``, strategy, swarm size, Monte-Carlo run
-count, seed, and a git-describable code version.  Because the executor
-backends are bit-identical (tested), the digest deliberately excludes the
-backend — a result computed by the streaming path on one host is a valid
-cache hit for a ``vmap`` re-run on another.
+count, seed, a git-describable code version and the jax version.
+Because the executor backends are bit-identical (tested), the digest
+deliberately excludes the backend — a result computed by the streaming
+path on one host is a valid cache hit for a ``vmap`` re-run on another.
 
 Layout under the store root::
 
@@ -33,6 +33,7 @@ import subprocess
 import time
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.checkpoint import ckpt
@@ -124,6 +125,9 @@ def point_digest(point: SweepPoint, version: Optional[str] = None) -> str:
         "num_runs": int(point.num_runs),
         "seed": int(point.seed),
         "code_version": version if version is not None else code_version(),
+        # the random streams are jax's: an upgrade can move every number
+        # with no change to this repo (jax 0.5 made threefry partitionable)
+        "jax": jax.__version__,
     }
     blob = json.dumps(payload, sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()

@@ -75,15 +75,16 @@ def decode_attention(q, k, v, pos, *, window=0, bk=DEFAULT_BK,
     qt = q.reshape(B, Hkv, G, hd)
     kt = jnp.swapaxes(k, 1, 2)                   # [B, Hkv, S, hd]
     vt = jnp.swapaxes(v, 1, 2)
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    pos_arr = jnp.reshape(jnp.asarray(pos, jnp.int32), (1,))
 
     grid = (B, Hkv, S // bk)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, bk=bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,),
-                         memory_space=pltpu.SMEM),
+            # the whole (1,) array in SMEM: a rank-1 block must span the
+            # array or a multiple of 128 lanes
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),

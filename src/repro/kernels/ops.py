@@ -1,9 +1,10 @@
 """jit'd dispatch layer: Pallas kernels on TPU, jnp references elsewhere.
 
-The model code calls these entry points; on this CPU-only container they
-route to ``ref.py`` (which the dry-run lowers), on a real TPU backend they
-route to the Pallas kernels.  ``REPRO_FORCE_INTERPRET=1`` forces the Pallas
-path in interpret mode (used by the kernel integration tests).
+The model code calls these entry points; on a TPU backend they route to
+the Pallas kernels, on any other backend to ``ref.py`` (which the dry-run
+lowers).  Off-TPU, ``REPRO_FORCE_INTERPRET=1`` forces the Pallas path in
+interpret mode (used by the kernel integration tests); a TPU backend
+ignores it, so a stray variable can never run the chip in interpret mode.
 """
 from __future__ import annotations
 
@@ -23,10 +24,10 @@ from repro.kernels.rmsnorm import rmsnorm as _pl_rmsnorm
 
 
 def _mode() -> str:
-    if os.environ.get("REPRO_FORCE_INTERPRET") == "1":
-        return "interpret"
     if jax.default_backend() == "tpu":
         return "tpu"
+    if os.environ.get("REPRO_FORCE_INTERPRET") == "1":
+        return "interpret"
     return "ref"
 
 

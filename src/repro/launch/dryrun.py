@@ -34,6 +34,7 @@ import traceback         # noqa: E402
 
 import jax               # noqa: E402
 
+from repro.chip import enable_compile_cache                          # noqa: E402
 from repro.configs import (ARCHS, SHAPES, get_config, get_shape,             # noqa: E402
                            shape_applicable)
 from repro.launch.mesh import make_production_mesh                           # noqa: E402
@@ -278,6 +279,7 @@ def main():
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--out", default=os.path.normpath(ARTIFACT_DIR))
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     archs = sorted(ARCHS) if (args.all or not args.arch) else [args.arch]

@@ -9,7 +9,7 @@ import math
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
@@ -19,14 +19,21 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     need = math.prod(shape)
     devs = jax.devices()
-    if len(devs) == need:
-        return jax.make_mesh(shape, axes)
     if len(devs) < need:
         raise RuntimeError(
             f"need {need} devices for mesh {shape}, have {len(devs)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_"
             "count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return auto_mesh(shape, axes, devices=devs[:need])
+
+
+def auto_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the model code
+    places arrays with ``NamedSharding`` hints and lets XLA propagate them,
+    which ``make_mesh``'s default ``Explicit`` axes reject (the embedding
+    gather would need an ``out_sharding``)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes_of(mesh) -> Tuple[str, ...]:

@@ -3,6 +3,11 @@ heterogeneous executors (the paper's protocol driving a real LM).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
         --requests 32 --batch 4
+
+On a TPU the model runs at its published widths; on any other backend
+its ``reduced`` variant (same code paths, tiny dims).  Sequence lengths
+that are multiples of 128 reach the Pallas flash-attention kernel on the
+chip; other lengths run the reference attention.
 """
 from __future__ import annotations
 
@@ -12,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.chip import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models import build_model
 from repro.splitcompute import SplitServeEngine, plan_stages
@@ -22,16 +28,19 @@ def main():
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--executors", type=int, default=4)
     ap.add_argument("--burst", type=int, default=0,
                     help="submit this many extra requests at once to trigger "
                          "the congestion-aware early exit")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = reduced(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if jax.default_backend() != "tpu":
+        cfg = reduced(cfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
 
     # heterogeneous executors (paper Table 2: N(400, 100) GFLOP/s)
     rng = np.random.default_rng(0)

@@ -12,6 +12,7 @@ import time
 
 import jax
 
+from repro.chip import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, batch_at
 from repro.launch.step import init_train_state, make_train_step
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size:

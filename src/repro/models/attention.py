@@ -129,7 +129,10 @@ def chunked_attention(q, k, v, *, q_positions, k_positions, causal=True,
 
     lax.scan over q chunks; per-chunk full-row scores (fp32 softmax).
     On TPU (and under REPRO_FORCE_INTERPRET) dispatches to the Pallas
-    flash-attention kernel when positions are the standard arange layout.
+    flash-attention kernel when positions are the standard arange layout
+    and both sequence lengths are multiples of 128 (the kernel's 128-row
+    tiles); any other length runs this reference path, on the chip too.
+    ``chip_smoke.py`` asserts that its serving run reaches the kernel.
     """
     if standard_layout:
         from repro.kernels import ops as kops
@@ -186,7 +189,9 @@ def decode_attention_ref(q, k_cache, v_cache, *, q_position, k_positions,
     Exact row softmax; with the cache S-dim sharded over 'model', XLA emits
     partial max/sum all-reduces (distributed flash-decode).  On TPU,
     arange-layout caches dispatch to the Pallas flash-decode kernel
-    (ring-buffer caches — non-monotone k_positions — stay on this path).
+    (ring-buffer caches — non-monotone k_positions — stay on this path);
+    so do caches whose length is not a multiple of 128 (the kernel's key
+    tile).
     """
     if standard_layout:
         from repro.kernels import ops as kops

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.common import dense_init
 
@@ -173,7 +172,7 @@ def apply_moe(p, cfg: ModelConfig, x, *, mesh=None,
         dropped = jax.lax.psum(dropped, model_axis) / n_shards
         return y.reshape(Bl, Sl, dl), aux, dropped
 
-    y, aux, dropped = shard_map(
+    y, aux, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(None, None), P(model_axis, fax, None),
                   P(model_axis, fax, None),

@@ -1,13 +1,14 @@
 """Batched-request serve engine over a φ-partitioned model.
 
 This is the end-to-end integration of the paper's protocol with real model
-execution: a reduced LM is split at vertical split points into stages
-(``plan_stages``), each stage is bound to a simulated heterogeneous
-executor, and requests flow stage→stage exactly like partial inferences
-flow UAV→UAV in the swarm.  The congestion-aware early exit (Eq. 14-16)
-monitors each executor's queue and truncates inference at the model's exit
-layers under load, trading accuracy (deeper logits) for latency — the LM
-analogue of the paper's accuracy levels.
+execution: an LM (published widths on a chip, ``reduced`` on a CPU) is
+split at vertical split points into stages (``plan_stages``), each stage
+is bound to a simulated heterogeneous executor, and requests flow
+stage→stage exactly like partial inferences flow UAV→UAV in the swarm.
+The congestion-aware early exit (Eq. 14-16) monitors each executor's
+queue and truncates inference at the model's exit layers under load,
+trading accuracy (deeper logits) for latency — the LM analogue of the
+paper's accuracy levels.
 
 Everything is functional JAX underneath (stage_apply slices the stacked
 layer tree), so the same engine drives the TPU mesh in production and the
@@ -263,11 +264,6 @@ class SplitServeEngine:
         self.params = params
         self.plan = plan
         self.n_stages = len(plan.executors)
-        # per-stage sliced params (static split-point extraction)
-        self.stage_params = [
-            slice_layers(params["layers"], plan.boundaries[i],
-                         plan.boundaries[i + 1])
-            for i in range(self.n_stages)]
         # early-exit bookkeeping per executor
         self.cong = CongestionState(jnp.zeros((self.n_stages,)),
                                     jnp.zeros((self.n_stages,)))
@@ -296,18 +292,24 @@ class SplitServeEngine:
         self._next_id = 0
         self._stage_fns = [self._make_stage_fn(i)
                            for i in range(self.n_stages)]
-        self._head_fn = jax.jit(
-            lambda h: head_out(self.params, self.cfg, h))
+        head = jax.jit(lambda p, h: head_out(p, self.cfg, h))
+        self._head_fn = lambda h: head(self.params, h)
 
     def _make_stage_fn(self, i):
-        sp = self.stage_params[i]
+        """Stage ``i`` runs its layer range of the one stacked copy in
+        ``params``: the static split-point slice is taken inside the jitted
+        stage, where XLA reads the weights in place, so no stage holds a
+        copy of its layers (at published widths a second copy would not
+        fit one chip next to the first)."""
+        lo, hi = self.plan.boundaries[i], self.plan.boundaries[i + 1]
 
         @jax.jit
-        def fn(h, positions):
-            h2, _, _ = run_layers(sp, self.cfg, h, positions, mode="train")
+        def run(layers, h, positions):
+            h2, _, _ = run_layers(slice_layers(layers, lo, hi), self.cfg, h,
+                                  positions, mode="train")
             return h2
 
-        return fn
+        return lambda h, positions: run(self.params["layers"], h, positions)
 
     # -- exit boundaries in *stage* space -----------------------------------
     def _exit_stage(self, label: int) -> int:

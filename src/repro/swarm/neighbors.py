@@ -126,6 +126,7 @@ def neighbor_lists(pos: jax.Array, cfg: SwarmConfig, k: int | None = None
     order = jnp.argsort(cid)                       # node ids sorted by cell
     scid = cid[order]
     cells = jnp.arange(G * G, dtype=cid.dtype)
+    # oob: searchsorted's own CLIP gathers (inside jax) read in-range
     starts = jnp.searchsorted(scid, cells)
     ends = jnp.searchsorted(scid, cells, side="right")
 

@@ -49,6 +49,7 @@ def make_profile(cfg: SwarmConfig) -> TaskProfile:
 def layer_of(profile: TaskProfile, cum_done: jax.Array) -> jax.Array:
     """Last *completed* layer boundary for a progress value (partial layer
     work does not count — §3.1 discard-on-offload)."""
+    # oob: searchsorted's own CLIP gathers (inside jax) read in-range
     return jnp.searchsorted(profile.cum_gflops, cum_done, side="right") - 1
 
 

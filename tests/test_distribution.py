@@ -20,20 +20,21 @@ import numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from repro.configs import get_config, reduced
-from repro.launch.mesh import (batch_axes_of, make_production_mesh,
-                               resolve_spec, sanitize_spec, shardings)
+from repro.launch.mesh import (auto_mesh, batch_axes_of,
+                               make_production_mesh, resolve_spec,
+                               sanitize_spec, shardings)
 from repro.models import build_model
 
 out = {}
 
 # --- mesh + spec resolution -------------------------------------------------
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = auto_mesh((4, 4), ("data", "model"))
 sp = sanitize_spec(P("model", "data"), (49155, 1024), mesh)
 out["sanitize_vocab"] = list(sp)           # model must drop (49155 % 4 != 0)
 sp2 = sanitize_spec(P("data", "model"), (64, 64), mesh)
 out["sanitize_ok"] = list(sp2)
 
-mp = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+mp = auto_mesh((2, 2, 4), ("pod", "data", "model"))
 rp = resolve_spec(P("data", None), mp)
 out["resolve_pod"] = [list(e) if isinstance(e, tuple) else e for e in rp]
 

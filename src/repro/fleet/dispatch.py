@@ -526,7 +526,6 @@ def main(argv=None) -> None:
     ap.add_argument("--progress", default=None,
                     help="progress.jsonl path (benchmarks/run.py --watch)")
     args = ap.parse_args(argv)
-    check_local_workers(args.workers)
     enable_compile_cache()
 
     store = ResultStore(args.store)

@@ -45,12 +45,6 @@ aggregate indices into ``artifacts/BENCH_fleet.json``.  Env knobs:
                                (on by default: tracing is compile-free);
                                REPRO_FLEET_FINGERPRINT_MAX caps points
 
-Every ``fleet_sweep`` additionally records each point's compile/execute
-wall-clock spans into the ``profile`` section of BENCH_fleet.json, each
-entry tagged with its ``host_class`` (``repro.obs.host_class``) so
-``benchmarks/perf_gate.py`` only hard-fails same-class comparisons and
-downgrades cross-class excesses to warnings (DESIGN.md §14.5).
-
 Multi-host mode: with the ``REPRO_FLEET_*`` rank/world env contract set
 (``fleet/dispatch.py``), every figure sweep runs as this rank's worker
 against the shared cache; only rank 0 records/returns results.
@@ -177,15 +171,6 @@ def fleet_sweep(spec: SweepSpec, backend: Optional[str] = None,
                          # per-point config → latency_segments critical-
                          # path attribution on traced points (§14.4)
                          cfg={pt.label: pt.cfg for pt in spec.expand()}))
-        payload = _profile_payload(spec, res, backend)
-        if payload:
-            # merge per sweep name: profile is the one BENCH section with
-            # wall-clock content, accumulated across producers (the perf
-            # gate compares it against the committed baseline)
-            from repro.fleet.report import load_bench_json
-            merged = dict(load_bench_json(BENCH_JSON).get("profile", {}))
-            merged[spec.name] = payload
-            write_bench_json(BENCH_JSON, "profile", merged)
         fps = _fingerprint_payload(spec)
         if fps:
             from repro.fleet.report import load_bench_json
@@ -204,8 +189,7 @@ def _fingerprint_payload(spec: SweepSpec) -> Dict:
     opts out and very large grids are capped (skipped points are counted
     in the payload, never silently dropped).  A tracing failure degrades
     to an ``error`` entry rather than failing the benchmark run: the
-    fingerprints section is diagnosis for the perf gate, not a gate on
-    producing numbers.
+    fingerprints section is diagnosis, not a gate on producing numbers.
     """
     if os.environ.get("REPRO_FLEET_FINGERPRINTS", "1") == "0":
         return {}
@@ -215,47 +199,6 @@ def _fingerprint_payload(spec: SweepSpec) -> Dict:
         return sweep_fingerprint_table(spec, max_points=cap)
     except Exception as e:  # diagnosis must not sink the producer
         return {"sweep": spec.name, "error": f"{type(e).__name__}: {e}"}
-
-
-def _profile_payload(spec: SweepSpec, res: Dict[str, Dict],
-                     backend: str) -> Dict:
-    """Per-point compile/execute wall-clock spans of one finished sweep.
-
-    The single-process ``execute`` path carries ``_compile_s`` /
-    ``_execute_s`` pseudo-metrics in ``res``; a dispatched sweep's results
-    come back clean from the store, so the spans are recovered from the
-    workers' ``point`` rows in progress.jsonl (last row per label wins —
-    that's the worker that actually computed it).  Cache-hit points record
-    ``cached: true`` with no spans: a hit cost no compile or execute time,
-    and the perf gate skips it.
-    """
-    from repro.fleet.dispatch import read_progress
-    from repro.obs import host_class
-
-    prog: Dict[str, Dict] = {}
-    for row in read_progress(PROGRESS_JSONL):
-        if row.get("event") == "point" and row.get("label"):
-            prog[row["label"]] = row
-    payload = {}
-    hc = host_class()
-    for label, m in res.items():
-        entry = {"backend": backend, "cached": True, "host_class": hc,
-                 "wall_s": None, "compile_s": None, "execute_s": None}
-        if m.get("_execute_s") is not None:
-            entry.update(cached=False,
-                         wall_s=round(float(m["_wall_s"]), 3),
-                         compile_s=round(float(m["_compile_s"]), 3),
-                         execute_s=round(float(m["_execute_s"]), 3))
-        elif "_wall_s" in m:
-            entry["wall_s"] = round(float(m["_wall_s"]), 3)
-        elif label in prog:     # dispatched: spans live in progress rows
-            row = prog[label]
-            entry.update(cached=bool(row.get("cached", False)),
-                         wall_s=row.get("wall_s"),
-                         compile_s=row.get("compile_s"),
-                         execute_s=row.get("execute_s"))
-        payload[label] = entry
-    return payload
 
 
 def timed_sweep(cfg: SwarmConfig, strategies: Sequence[int], n: int,

@@ -5,9 +5,9 @@ sweeps into BENCH_fleet.json — without executing the sweeps.
 so fingerprinting the full Fig. 3 / Fig. 5 grids costs seconds where
 running them costs minutes.  The tables land in the ``fingerprints``
 BENCH section (the same one ``fleet_sweep`` maintains as a side effect of
-real runs, benchmarks/common.py), keyed by sweep name; perf_gate.py reads
-them to say *which point started recompiling* when an execute span
-regresses (DESIGN.md §15.3).
+real runs, benchmarks/common.py), keyed by sweep name: they say *which
+point started recompiling* when a sweep's compile time jumps (DESIGN.md
+§15.3).
 
 ``--check`` turns instability into exit 1: if any same-structural-
 signature group of points traces distinct programs, a config field that

@@ -7,7 +7,7 @@ changes — gamma, arrival rates, channel parameters — retrace to the
 values never reach program *structure*.  A "leaked static arg" breaks
 that silently: a python-level branch on a float, a shape derived from a
 parameter, a host-side rounding — and suddenly every grid cell of a
-sweep compiles its own executable.  The perf gate sees the compile-time
+sweep compiles its own executable.  A sweep sees the compile-time
 cliff but cannot say *which point* started recompiling.
 
 This module makes the contract checkable:
@@ -173,7 +173,7 @@ def sweep_fingerprint_table(spec, max_points: Optional[int] = None
                             ) -> Dict[str, Any]:
     """Fingerprint every point of a sweep; the dict lands under
     ``fingerprints:<sweep>`` in BENCH_fleet.json (benchmarks/common.py)
-    so the perf gate can name which point started recompiling.
+    and names which point started recompiling.
 
     ``max_points`` caps tracing cost for very large grids (points beyond
     the cap are reported as skipped, never silently dropped).

@@ -18,6 +18,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import phase
+
 NEG = -1e30
 
 
@@ -38,14 +40,15 @@ def phi_update(phi: jax.Array, F: jax.Array, adj: jax.Array,
 
     Isolated nodes (|M_i| = 0) fall back to φ_i = F_i.
     """
-    inv_phi = 1.0 / phi                                     # [N] s/GFLOP
-    # worst collaborator: max_k ( d_tx[i,k] + 1/phi_k ) over neighbors
-    cand = jnp.where(adj, d_tx + inv_phi[None, :], NEG)     # [N, N]
-    worst = jnp.max(cand, axis=1)                           # [N]
-    deg = jnp.sum(adj, axis=1)                              # [N]
-    inv_new = (1.0 / F + worst) / (deg + 1.0)
-    phi_new = 1.0 / inv_new
-    return jnp.where(deg > 0, phi_new, F)
+    with phase("phi_update"):
+        inv_phi = 1.0 / phi                                 # [N] s/GFLOP
+        # worst collaborator: max_k ( d_tx[i,k] + 1/phi_k ) over neighbors
+        cand = jnp.where(adj, d_tx + inv_phi[None, :], NEG)  # [N, N]
+        worst = jnp.max(cand, axis=1)                       # [N]
+        deg = jnp.sum(adj, axis=1)                          # [N]
+        inv_new = (1.0 / F + worst) / (deg + 1.0)
+        phi_new = 1.0 / inv_new
+        return jnp.where(deg > 0, phi_new, F)
 
 
 def phi_update_op(phi: jax.Array, F: jax.Array, adj: jax.Array,
@@ -61,14 +64,16 @@ def phi_update_op(phi: jax.Array, F: jax.Array, adj: jax.Array,
     """
     from repro.kernels import ops  # deferred: keep core import-light
 
-    inv_phi = 1.0 / phi
-    dtx_m = jnp.where(adj, d_tx, NEG)
-    if inv_phi.ndim == 1:
-        inv_new = ops.diffusive_phi(inv_phi[None], F[None], dtx_m[None])[0]
-    else:
-        inv_new = ops.diffusive_phi(inv_phi, F, dtx_m)
-    deg = jnp.sum(adj, axis=-1)
-    return jnp.where(deg > 0, 1.0 / inv_new, F)
+    with phase("phi_update"):
+        inv_phi = 1.0 / phi
+        dtx_m = jnp.where(adj, d_tx, NEG)
+        if inv_phi.ndim == 1:
+            inv_new = ops.diffusive_phi(inv_phi[None], F[None],
+                                        dtx_m[None])[0]
+        else:
+            inv_new = ops.diffusive_phi(inv_phi, F, dtx_m)
+        deg = jnp.sum(adj, axis=-1)
+        return jnp.where(deg > 0, 1.0 / inv_new, F)
 
 
 def phi_update_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
@@ -81,12 +86,13 @@ def phi_update_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
     cover every dense neighbor (same candidates, same arithmetic; max is
     order-independent).
     """
-    inv_phi = 1.0 / phi
-    cand = jnp.where(adj_e, d_tx_e + inv_phi[nbr], NEG)     # [N, K]
-    worst = jnp.max(cand, axis=-1)
-    deg = jnp.sum(adj_e, axis=-1)
-    inv_new = (1.0 / F + worst) / (deg + 1.0)
-    return jnp.where(deg > 0, 1.0 / inv_new, F)
+    with phase("phi_update"):
+        inv_phi = 1.0 / phi
+        cand = jnp.where(adj_e, d_tx_e + inv_phi[nbr], NEG)  # [N, K]
+        worst = jnp.max(cand, axis=-1)
+        deg = jnp.sum(adj_e, axis=-1)
+        inv_new = (1.0 / F + worst) / (deg + 1.0)
+        return jnp.where(deg > 0, 1.0 / inv_new, F)
 
 
 def phi_update_op_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
@@ -100,15 +106,16 @@ def phi_update_op_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
     """
     from repro.kernels import ops  # deferred: keep core import-light
 
-    inv_phi = 1.0 / phi
-    dtx_m = jnp.where(adj_e, d_tx_e, NEG)
-    if inv_phi.ndim == 1:
-        inv_new = ops.diffusive_phi_sparse(inv_phi[None], F[None],
-                                           dtx_m[None], nbr[None])[0]
-    else:
-        inv_new = ops.diffusive_phi_sparse(inv_phi, F, dtx_m, nbr)
-    deg = jnp.sum(adj_e, axis=-1)
-    return jnp.where(deg > 0, 1.0 / inv_new, F)
+    with phase("phi_update"):
+        inv_phi = 1.0 / phi
+        dtx_m = jnp.where(adj_e, d_tx_e, NEG)
+        if inv_phi.ndim == 1:
+            inv_new = ops.diffusive_phi_sparse(inv_phi[None], F[None],
+                                               dtx_m[None], nbr[None])[0]
+        else:
+            inv_new = ops.diffusive_phi_sparse(inv_phi, F, dtx_m, nbr)
+        deg = jnp.sum(adj_e, axis=-1)
+        return jnp.where(deg > 0, 1.0 / inv_new, F)
 
 
 def phi_fixpoint(F: jax.Array, adj: jax.Array, d_tx: jax.Array,

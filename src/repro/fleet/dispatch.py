@@ -39,8 +39,7 @@ system gauges (mean/max queue depth, φ spread, completion rate;
 ``benchmarks/loadtest.py`` streams its SLO gauges — p50/p99 latency,
 goodput, drop rate — onto the same rows, DESIGN.md §14.3) — and
 computed point rows carry the executor's ``compile_s`` / ``execute_s``
-spans, which ``benchmarks/common.fleet_sweep`` folds into the BENCH
-``profile`` section.
+spans.
 
 Env contract (remote mode — set per host, then run
 ``python -m repro.fleet.dispatch`` on each)::

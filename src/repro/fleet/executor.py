@@ -44,14 +44,22 @@ as dispatching through ``jit``, so numerics are bit-identical; pinned by
 ``tests/test_state_trace.py``), which splits the first-call wall clock
 into an honest *compile* span and an *execute* span.  ``run_point``
 surfaces them as ``_compile_s`` / ``_execute_s`` pseudo-metrics (leading
-underscore: skipped by reports, never stored) and they land in the
-``profile`` section of BENCH_fleet.json via ``benchmarks/common.py``.
-Executables are cached per (cfg, n, run-shape) — cache hits repeat the
-original compile span, which is the cost a cold worker would pay.
+underscore: skipped by reports, never stored), which land in the
+``point`` rows of progress.jsonl.  Executables are cached per (cfg, n,
+run-shape) — cache hits repeat the original compile span, which is the
+cost a cold worker would pay.
+
+On a profiler trace (``jax.profiler``), each ``run_batch`` call is a step
+span named ``run_batch`` whose ``step_num`` counts the calls of this
+process, and a ``compile`` span inside it marks a real compile (an
+executable-cache miss).  ``op_scopes`` maps the instructions of a cached
+executable to the simulator phases they belong to (``repro.obs.scopes``),
+the key for reading a device trace's op names by phase.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 import time
 from typing import Dict, Optional
 
@@ -62,10 +70,13 @@ import numpy as np
 from repro.configs.base import SwarmConfig
 from repro.fleet.store import ResultStore, code_version, point_digest
 from repro.fleet.sweep import SweepPoint, SweepSpec
+from repro.obs import scopes
 from repro.swarm.simulator import run_sim
 
 BACKENDS = ("vmap", "sharded", "streaming")
 DEFAULT_CHUNK = 8
+# run_batch calls in this process: the step number of each one's span
+_EXECUTIONS = itertools.count()
 
 
 class SweepInterrupted(RuntimeError):
@@ -100,10 +111,12 @@ _I32 = jax.ShapeDtypeStruct((), jnp.int32)
 def _profiled_vmap(cfg: SwarmConfig, n: int, num_runs: int):
     """AOT executable for the vmap backend + its compile-span seconds."""
     def fn(key, strategy):
-        keys = jax.random.split(key, num_runs)
+        with scopes.phase("init"):
+            keys = jax.random.split(key, num_runs)
         return jax.vmap(lambda k: run_sim(k, cfg, strategy, n))(keys)
     t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(_key_struct(), _I32).compile()
+    with jax.profiler.TraceAnnotation(scopes.COMPILE):
+        compiled = jax.jit(fn).lower(_key_struct(), _I32).compile()
     return compiled, time.perf_counter() - t0
 
 
@@ -120,7 +133,8 @@ def _profiled_sharded(cfg: SwarmConfig, n: int, padded: int, mesh):
     ks = _key_struct()
     keys_struct = jax.ShapeDtypeStruct((padded,) + ks.shape, ks.dtype)
     t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(keys_struct, _I32).compile()
+    with jax.profiler.TraceAnnotation(scopes.COMPILE):
+        compiled = jax.jit(fn).lower(keys_struct, _I32).compile()
     return compiled, time.perf_counter() - t0
 
 
@@ -137,9 +151,46 @@ def _profiled_stream(cfg: SwarmConfig, n: int, chunk: int, donate: bool):
     ks = _key_struct()
     keys_struct = jax.ShapeDtypeStruct((chunk,) + ks.shape, ks.dtype)
     t0 = time.perf_counter()
-    compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
-        keys_struct, _I32).compile()
+    with jax.profiler.TraceAnnotation(scopes.COMPILE):
+        compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
+            keys_struct, _I32).compile()
     return compiled, time.perf_counter() - t0
+
+
+def _padded_runs(num_runs: int) -> int:
+    """Run count of the sharded backend: padded up to the device count."""
+    d = jax.device_count()
+    return (num_runs + d - 1) // d * d
+
+
+def _chunk(chunk_size: int, num_runs: int) -> int:
+    return max(1, min(chunk_size, num_runs))
+
+
+def _executable(cfg: SwarmConfig, n: int, num_runs: int, backend: str,
+                chunk_size: int = DEFAULT_CHUNK):
+    """The cached executable of one backend, and its compile seconds."""
+    if backend == "vmap":
+        return _profiled_vmap(cfg, n, num_runs)
+    if backend == "sharded":
+        from jax.sharding import Mesh
+        mesh = Mesh(np.asarray(jax.devices()), ("mc",))
+        return _profiled_sharded(cfg, n, _padded_runs(num_runs), mesh)
+    if backend == "streaming":
+        return _profiled_stream(cfg, n, _chunk(chunk_size, num_runs),
+                                jax.default_backend() != "cpu")
+    raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
+
+
+def op_scopes(cfg: SwarmConfig, n: int, num_runs: int,
+              backend: str = "vmap") -> Dict[str, str]:
+    """Instruction name (``fusion.780``) -> simulator phase of every op of
+    the executable ``run_batch`` runs for these arguments that lies in a
+    phase, read from its HLO text.  A device trace names its ops by these
+    instruction names.  The executable comes from the cache, so nothing
+    compiles twice."""
+    compiled, _ = _executable(cfg, n, num_runs, backend)
+    return scopes.op_scopes(compiled.as_text())
 
 
 def _block(out):
@@ -148,12 +199,8 @@ def _block(out):
 
 def _run_sharded(key, cfg: SwarmConfig, strategy, n: int, num_runs: int,
                  spans: Optional[Dict] = None):
-    from jax.sharding import Mesh
-    devs = np.asarray(jax.devices())
-    mesh = Mesh(devs, ("mc",))
-    padded = (num_runs + len(devs) - 1) // len(devs) * len(devs)
-    keys = _pad_keys(jax.random.split(key, num_runs), padded)
-    compiled, compile_s = _profiled_sharded(cfg, n, padded, mesh)
+    keys = _pad_keys(jax.random.split(key, num_runs), _padded_runs(num_runs))
+    compiled, compile_s = _executable(cfg, n, num_runs, "sharded")
     t0 = time.perf_counter()
     out = _block(compiled(keys, jnp.asarray(strategy, jnp.int32)))
     if spans is not None:
@@ -185,12 +232,12 @@ def _run_streaming(key, cfg: SwarmConfig, strategy, n: int, num_runs: int,
                    spans: Optional[Dict] = None,
                    progress=None, label: Optional[str] = None
                    ) -> Dict[str, np.ndarray]:
-    chunk = max(1, min(chunk_size, num_runs))
+    chunk = _chunk(chunk_size, num_runs)
     n_chunks = (num_runs + chunk - 1) // chunk
     keys = jax.random.split(key, num_runs)
     strategy = jnp.asarray(strategy, jnp.int32)
-    compiled, compile_s = _profiled_stream(
-        cfg, n, chunk, jax.default_backend() != "cpu")
+    compiled, compile_s = _executable(cfg, n, num_runs, "streaming",
+                                      chunk_size)
     if spans is not None:
         spans["_compile_s"] = compile_s
         spans.setdefault("_execute_s", 0.0)
@@ -243,23 +290,30 @@ def run_batch(key, cfg: SwarmConfig, strategy, n: int, num_runs: int, *,
     over the ``vmap`` backend of this function.  Passing a ``spans`` dict
     fills ``"_compile_s"`` / ``"_execute_s"`` wall-clock spans (the
     execute span blocks on the result).
+
+    On a profiler trace the call is a ``run_batch`` step span: argument
+    preparation, any compile, dispatch, and the block where one happens
+    (with ``spans``, and always on the sharded and streaming backends).
     """
-    if backend == "vmap":
-        compiled, compile_s = _profiled_vmap(cfg, n, num_runs)
-        t0 = time.perf_counter()
-        out = compiled(key, jnp.asarray(strategy, jnp.int32))
-        if spans is not None:
-            _block(out)
-            spans["_compile_s"] = compile_s
-            spans["_execute_s"] = time.perf_counter() - t0
-        return out
-    if backend == "sharded":
-        return _run_sharded(key, cfg, strategy, n, num_runs, spans=spans)
-    if backend == "streaming":
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
+    with jax.profiler.StepTraceAnnotation(scopes.RUN_BATCH,
+                                          step_num=next(_EXECUTIONS)):
+        if backend == "vmap":
+            compiled, compile_s = _profiled_vmap(cfg, n, num_runs)
+            t0 = time.perf_counter()
+            out = compiled(key, jnp.asarray(strategy, jnp.int32))
+            if spans is not None:
+                _block(out)
+                spans["_compile_s"] = compile_s
+                spans["_execute_s"] = time.perf_counter() - t0
+            return out
+        if backend == "sharded":
+            return _run_sharded(key, cfg, strategy, n, num_runs,
+                                spans=spans)
         return {k: jnp.asarray(v) for k, v in _run_streaming(
             key, cfg, strategy, n, num_runs, chunk_size,
             spans=spans).items()}
-    raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
 
 
 def run_point(point: SweepPoint, *, backend: str = "vmap",
@@ -333,7 +387,7 @@ def execute(spec: SweepSpec, *, backend: str = "vmap",
         m["_wall_s"] = time.perf_counter() - t0
         # computed points carry the AOT compile/execute split (a store hit
         # fills neither); reports skip underscore keys, so these are purely
-        # for the profile section / progress surface
+        # for the progress surface
         m["_compile_s"] = spans.get("_compile_s")
         m["_execute_s"] = spans.get("_execute_s")
         if verbose:

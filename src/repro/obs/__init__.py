@@ -29,10 +29,10 @@ __all__ = ["HistSpec", "DEFAULT_LATENCY_HIST", "SLO_QS", "edges", "empty",
 
 
 def host_class() -> str:
-    """Coarse machine-class identifier for perf-profile comparability
-    (DESIGN.md §14.5): OS, ISA, and physical core count — enough to tell
-    "same class of box" from "CI runner vs laptop" without fingerprinting
-    the exact host.  Override with ``REPRO_HOST_CLASS`` for fleets whose
+    """Coarse machine-class identifier stamped on host-timed results
+    (``slo_serve``, ``benchmarks/loadtest.py``): OS, ISA, and core count —
+    enough to tell "same class of box" from "CI runner vs laptop" without
+    fingerprinting the exact host.  Override with ``REPRO_HOST_CLASS`` for fleets whose
     hardware labels don't reduce to these fields.
     """
     override = os.environ.get("REPRO_HOST_CLASS")

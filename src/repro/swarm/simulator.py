@@ -32,6 +32,7 @@ from repro.core.diffusive import phi_update_op, phi_update_op_sparse
 from repro.core.early_exit import (congestion_update, exit_accuracy,
                                    exit_boundary_layers, exit_label)
 from repro.core.early_exit import CongestionState
+from repro.obs.scopes import phase
 from repro.swarm import transfer as transfer_mod
 from repro.swarm.channel import edge_rate, link_state, link_state_sparse
 from repro.swarm.neighbors import mask_neighbors, neighbor_lists
@@ -147,21 +148,24 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
                                                   dtype=jnp.int32)
     st["lat_sum"] = st["lat_sum"] + jnp.sum(jnp.where(completed, lat, 0.0))
     st["acc_sum"] = st["acc_sum"] + jnp.sum(jnp.where(completed, acc, 0.0))
-    # oob: in-range `head` (argmin), see the q_cum scatter above (J003)
-    st["q_active"] = st["q_active"].at[rows, head].set(
-        jnp.where(completed, False, st["q_active"][rows, head]))
+    with phase("queues"):   # a completed head leaves its slot: a pop
+        # oob: in-range `head` (argmin), see the q_cum scatter above (J003)
+        st["q_active"] = st["q_active"].at[rows, head].set(
+            jnp.where(completed, False, st["q_active"][rows, head]))
     if trace_record.enabled(cfg):
-        # oob: in-range `head` (argmin); add-where-inactive is masked by
-        # adv == 0 on empty queues (J003)
-        st["q_energy"] = st["q_energy"].at[rows, head].add(adv * eJ)
-        st = trace_record.write_records(
-            st, completed, seq=st["q_seq"][rows, head],
-            src=st["q_src"][rows, head], dst=rows,
-            created_t=st["q_created"][rows, head], completed_t=t_now,
-            exit_label=st["xi_label"], layers=st["xi_layers"],
-            hops=jnp.sum(st["q_visited"][rows, head], axis=-1),
-            energy_j=st["q_energy"][rows, head],
-            tx_time_s=st["q_txtime"][rows, head])
+        with phase("trace_capture"):
+            # oob: in-range `head` (argmin); add-where-inactive is masked
+            # by adv == 0 on empty queues (J003)
+            st["q_energy"] = st["q_energy"].at[rows, head].add(adv * eJ)
+            with phase("visited"):
+                hops = jnp.sum(st["q_visited"][rows, head], axis=-1)
+            st = trace_record.write_records(
+                st, completed, seq=st["q_seq"][rows, head],
+                src=st["q_src"][rows, head], dst=rows,
+                created_t=st["q_created"][rows, head], completed_t=t_now,
+                exit_label=st["xi_label"], layers=st["xi_layers"],
+                hops=hops, energy_j=st["q_energy"][rows, head],
+                tx_time_s=st["q_txtime"][rows, head])
     return st, budget - adv
 
 
@@ -172,29 +176,32 @@ def _tick(st, key, cfg: SwarmConfig, profile: TaskProfile, cap, alive,
 
     # (a) Markov-modulated arrivals (down nodes don't generate)
     st = dict(st)
-    st["burst_on"], arrive = burst_arrivals(st["burst_on"], key, cfg)
-    arrive = arrive & alive
-    if trace_record.enabled(cfg):
-        st = trace_record.traced_push(
-            st, arrive, jnp.zeros((n,), jnp.float32),
-            jnp.full((n,), t_now), jnp.zeros((n, n), bool),
-            src=jnp.arange(n), energy=0.0,
-            txtime=0.0, t_now=t_now, cfg=cfg)
-    else:
-        st = push(st, arrive, jnp.zeros((n,), jnp.float32),
-                  jnp.full((n,), t_now), jnp.zeros((n, n), bool))
-    st["gen_count"] = st["gen_count"] + jnp.sum(arrive, dtype=jnp.int32)
+    with phase("arrivals"):
+        st["burst_on"], arrive = burst_arrivals(st["burst_on"], key, cfg)
+        arrive = arrive & alive
+        if trace_record.enabled(cfg):
+            st = trace_record.traced_push(
+                st, arrive, jnp.zeros((n,), jnp.float32),
+                jnp.full((n,), t_now), jnp.zeros((n, n), bool),
+                src=jnp.arange(n), energy=0.0,
+                txtime=0.0, t_now=t_now, cfg=cfg)
+        else:
+            st = push(st, arrive, jnp.zeros((n,), jnp.float32),
+                      jnp.full((n,), t_now), jnp.zeros((n, n), bool))
+        st["gen_count"] = st["gen_count"] + jnp.sum(arrive, dtype=jnp.int32)
 
     # (b) compute (budget cascade x2: finish a task and start the next;
     #     down nodes hold their queues but burn no cycles)
-    targets = profile.cum_gflops[jnp.clip(st["xi_layers"], 0,
-                                          profile.gflops.shape[0])]
-    budget = jnp.where(alive, st["F"] * tick, 0.0)
-    for _ in range(2):
-        st, budget = _compute_pass(st, budget, targets, t_now, cfg)
+    with phase("compute"):
+        targets = profile.cum_gflops[jnp.clip(st["xi_layers"], 0,
+                                              profile.gflops.shape[0])]
+        budget = jnp.where(alive, st["F"] * tick, 0.0)
+        for _ in range(2):
+            st, budget = _compute_pass(st, budget, targets, t_now, cfg)
 
     # (c) transfer progress + delivery
-    return transfer_mod.progress(st, cap, alive, cfg, t_now)
+    with phase("transfers"):
+        return transfer_mod.progress(st, cap, alive, cfg, t_now)
 
 
 # ---------------------------------------------------------------------------
@@ -236,7 +243,8 @@ def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
     random_ = (r_do, r_tgt)
 
     # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
-    visited_head = st["q_visited"][rows, head]              # [N, N]
+    with phase("visited"):
+        visited_head = st["q_visited"][rows, head]          # [N, N]
     amask = adj & ~visited_head
     a_has = jnp.any(amask, axis=1)
     a_tgt = jnp.argmax(jnp.where(amask, jax.random.gumbel(k3, (n, n)), -BIG),
@@ -298,8 +306,10 @@ def _strategy_decision_sparse(st, strategy, adj_e, nbr, d_tx_e, T, key,
     # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
     # the visited sets stay dense [N, Q, N] (a bitset redesign is ROADMAP
     # work); the epoch cost here is only the [N, K] gather of head rows
-    visited_head = st["q_visited"][rows, head]              # [N, N]
-    amask = adj_e & ~visited_head[rows[:, None], nbr]
+    with phase("visited"):
+        visited_head = st["q_visited"][rows, head]          # [N, N]
+        visited_nbr = visited_head[rows[:, None], nbr]      # [N, K]
+    amask = adj_e & ~visited_nbr
     a_has = jnp.any(amask, axis=1)
     a_tgt = nbr[rows, jnp.argmax(
         jnp.where(amask, jax.random.gumbel(k3, (n, K)), -BIG), axis=1)]
@@ -324,71 +334,84 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
     # kd/kt reproduce the pre-engine key streams exactly; scenario keys are
     # folded off the epoch key so the default scenario stays bit-identical
     # (except Random/RandomAcyclic, whose key-reuse fix below is deliberate).
-    kd, kt = jax.random.split(key)
-    k_mob = jax.random.fold_in(key, 11)
-    k_ch = jax.random.fold_in(key, 13)
-    k_fault = jax.random.fold_in(key, 17)
+    with phase("keys"):
+        kd, kt = jax.random.split(key)
+        k_mob = jax.random.fold_in(key, 11)
+        k_ch = jax.random.fold_in(key, 13)
+        k_fault = jax.random.fold_in(key, 17)
 
     # 1. refresh the scenario at epoch start; 2. strategy decision (Alg. 1
     #    lines 2-5).  neighbor_mode is static config, so the branch picks
     #    the compiled representation: dense [N, N] (the historical
     #    bit-exact path) or [N, K] neighbor lists (O(N·k), DESIGN.md §11)
     st = dict(st)
-    st["alive"] = get_fault(cfg).step(st["alive"], k_fault, cfg)
-    st["mob"], pos = get_mobility(cfg).step(st["mob"], k_mob, cfg, t0)
-    T = queued_gflops(st, profile)
+    with phase("faults"):
+        st["alive"] = get_fault(cfg).step(st["alive"], k_fault, cfg)
+    with phase("mobility"):
+        st["mob"], pos = get_mobility(cfg).step(st["mob"], k_mob, cfg, t0)
+    with phase("decision"):
+        T = queued_gflops(st, profile)
     sparse = cfg.neighbor_mode == "sparse"
     if sparse:
         edge_fn = get_channel_edges(cfg)
-        nbr, valid = neighbor_lists(pos, cfg)
-        valid = mask_neighbors(valid, nbr, st["alive"])
-        adj_e, cap_e = link_state_sparse(pos, nbr, valid, cfg, key=k_ch,
-                                         pathloss_fn=edge_fn)
-        d_tx_e = jnp.where(adj_e, profile.bits_per_gflop / cap_e, BIG)
-        do, tgt, phi = _strategy_decision_sparse(st, strategy, adj_e, nbr,
-                                                 d_tx_e, T, kd, cfg)
+        with phase("neighbors"):
+            nbr, valid = neighbor_lists(pos, cfg)
+            valid = mask_neighbors(valid, nbr, st["alive"])
+        with phase("channel"):
+            adj_e, cap_e = link_state_sparse(pos, nbr, valid, cfg, key=k_ch,
+                                             pathloss_fn=edge_fn)
+            d_tx_e = jnp.where(adj_e, profile.bits_per_gflop / cap_e, BIG)
+        with phase("decision"):
+            do, tgt, phi = _strategy_decision_sparse(
+                st, strategy, adj_e, nbr, d_tx_e, T, kd, cfg)
     else:
-        adj, cap = link_state(pos, cfg, key=k_ch,
-                              pathloss_fn=get_channel(cfg))
-        adj = mask_adjacency(adj, st["alive"])
-        d_tx = jnp.where(adj, profile.bits_per_gflop / cap, BIG)
-        do, tgt, phi = _strategy_decision(st, strategy, adj, d_tx, T, kd,
-                                          cfg)
+        with phase("channel"):
+            adj, cap = link_state(pos, cfg, key=k_ch,
+                                  pathloss_fn=get_channel(cfg))
+            adj = mask_adjacency(adj, st["alive"])
+            d_tx = jnp.where(adj, profile.bits_per_gflop / cap, BIG)
+        with phase("decision"):
+            do, tgt, phi = _strategy_decision(st, strategy, adj, d_tx, T,
+                                              kd, cfg)
     st["phi"] = phi
 
     # 3. congestion-aware early exit (Alg. 1 lines 10-11, Eqs. 14-16)
-    cong = congestion_update(
-        CongestionState(st["cong_prev"], st["cong_D"]), T,
-        cfg.decision_period_s, cfg.ema_alpha)
-    st["cong_prev"], st["cong_D"] = cong.prev_T, cong.D
-    if cfg.early_exit_enabled:
-        lbl = exit_label(cong.D, *cfg.exit_thresholds)
-    else:
-        lbl = jnp.zeros((st["F"].shape[0],), jnp.int32)
-    st["xi_label"] = lbl
-    st["xi_layers"] = exit_boundary_layers(lbl, cfg.exit_points,
-                                           cfg.exit_finalize_layers)
+    with phase("early_exit"):
+        cong = congestion_update(
+            CongestionState(st["cong_prev"], st["cong_D"]), T,
+            cfg.decision_period_s, cfg.ema_alpha)
+        st["cong_prev"], st["cong_D"] = cong.prev_T, cong.D
+        if cfg.early_exit_enabled:
+            lbl = exit_label(cong.D, *cfg.exit_thresholds)
+        else:
+            lbl = jnp.zeros((st["F"].shape[0],), jnp.int32)
+        st["xi_label"] = lbl
+        st["xi_layers"] = exit_boundary_layers(lbl, cfg.exit_points,
+                                               cfg.exit_finalize_layers)
 
     # 4. initiate transfers: pop head, snap to boundary (§3.1 discard)
-    _, has = head_slot(st)
-    elig = do & has & ~st["tx_active"] & (tgt >= 0)
-    st = transfer_mod.initiate(st, elig, tgt, t0, profile)
+    with phase("initiate"):
+        _, has = head_slot(st)
+        elig = do & has & ~st["tx_active"] & (tgt >= 0)
+        st = transfer_mod.initiate(st, elig, tgt, t0, profile)
 
     # 5. fine ticks.  tx_dst is frozen between decisions, so the sparse
     #    path resolves each node's outgoing link rate [N] once per epoch
     #    instead of carrying the [N, N] capacity matrix into the scan —
     #    same epoch key, so stochastic draws match the decision stage's
     if sparse:
-        link = edge_rate(pos, st["tx_dst"], cfg, key=k_ch,
-                         pathloss_fn=edge_fn)
+        with phase("channel"):
+            link = edge_rate(pos, st["tx_dst"], cfg, key=k_ch,
+                             pathloss_fn=edge_fn)
     else:
         link = cap
     n_ticks = int(round(cfg.decision_period_s / cfg.tick_s))
 
     def tick_body(st, i):
         t_now = t0 + (i.astype(jnp.float32) + 1.0) * cfg.tick_s
-        st = _tick(st, jax.random.fold_in(kt, i), cfg, profile, link,
-                   st["alive"], t_now)
+        with phase("keys"):
+            k = jax.random.fold_in(kt, i)
+        st = _tick(st, k, cfg, profile, link, st["alive"], t_now)
         return st, None
 
     st, _ = jax.lax.scan(tick_body, st, jnp.arange(n_ticks))
@@ -396,8 +419,9 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
     # 6. flight recorder: snapshot node gauges + system aggregates at the
     #    end of every trace_state_every-th epoch (DESIGN.md §12)
     if trace_record.state_enabled(cfg):
-        st = trace_record.write_state(st, epoch_idx,
-                                      t0 + cfg.decision_period_s, cfg)
+        with phase("trace_capture"):
+            st = trace_record.write_state(st, epoch_idx,
+                                          t0 + cfg.decision_period_s, cfg)
     return st
 
 
@@ -410,17 +434,20 @@ def run_sim(key, cfg: SwarmConfig, strategy, n: int | None = None) -> Dict:
     """One full simulation; returns the metric dict (see summarize)."""
     n = n or cfg.num_workers
     profile = make_profile(cfg)
-    k_init, k_run = jax.random.split(key)
-    st = init_state(k_init, cfg, n)
+    with phase("init"):
+        k_init, k_run = jax.random.split(key)
+        st = init_state(k_init, cfg, n)
     n_epochs = int(round(cfg.sim_time_s / cfg.decision_period_s))
 
     def body(st, i):
-        st = _epoch(st, jax.random.fold_in(k_run, i), i, strategy, cfg,
-                    profile)
+        with phase("keys"):
+            k = jax.random.fold_in(k_run, i)
+        st = _epoch(st, k, i, strategy, cfg, profile)
         return st, None
 
     st, _ = jax.lax.scan(body, st, jnp.arange(n_epochs))
-    return summarize(st, cfg, profile)
+    with phase("summarize"):
+        return summarize(st, cfg, profile)
 
 
 def summarize(st, cfg: SwarmConfig, profile: TaskProfile) -> Dict:

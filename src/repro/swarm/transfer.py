@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
+from repro.obs.scopes import phase
 from repro.swarm.queues import INT_MAX, head_slot, pop_head, push
 from repro.swarm.tasks import (TaskProfile, boundary_bits, layer_of,
                                snap_to_boundary)
@@ -38,31 +39,34 @@ def initiate(st, elig, tgt, t0, profile: TaskProfile):
     bits = boundary_bits(profile, cum_h)
     st = dict(st)
     if "tx_src" in st:       # trace attribution rides along (DESIGN §10.2)
-        for f in ("src", "energy", "txtime"):
-            st[f"tx_{f}"] = jnp.where(elig, st[f"q_{f}"][rows, head],
-                                      st[f"tx_{f}"])
+        with phase("trace_capture"):
+            for f in ("src", "energy", "txtime"):
+                st[f"tx_{f}"] = jnp.where(elig, st[f"q_{f}"][rows, head],
+                                          st[f"tx_{f}"])
     if "hop_seq" in st:      # hop stream: assign seqs at initiation (§10.5)
-        # i32-pinned reductions: numpy-style widening to i64 under x64
-        # would drift the hop-seq carry dtype (swarmlint J002)
-        hseq = st["hop_counter"] + jnp.cumsum(
-            elig.astype(jnp.int32), dtype=jnp.int32) - 1
-        st["hop_seq"] = jnp.where(elig, hseq, st["hop_seq"])
-        st["hop_counter"] = st["hop_counter"] + jnp.sum(
-            elig.astype(jnp.int32), dtype=jnp.int32)
-        st["hop_bits"] = jnp.where(elig, bits, st["hop_bits"])
-        st["hop_layer"] = jnp.where(
-            elig, jnp.clip(layer_of(profile, cum_h), 0,
-                           profile.cum_gflops.shape[0] - 1),
-            st["hop_layer"])
-        st["hop_stall"] = jnp.where(elig, 0, st["hop_stall"])
+        with phase("trace_capture"):
+            # i32-pinned reductions: numpy-style widening to i64 under x64
+            # would drift the hop-seq carry dtype (swarmlint J002)
+            hseq = st["hop_counter"] + jnp.cumsum(
+                elig.astype(jnp.int32), dtype=jnp.int32) - 1
+            st["hop_seq"] = jnp.where(elig, hseq, st["hop_seq"])
+            st["hop_counter"] = st["hop_counter"] + jnp.sum(
+                elig.astype(jnp.int32), dtype=jnp.int32)
+            st["hop_bits"] = jnp.where(elig, bits, st["hop_bits"])
+            st["hop_layer"] = jnp.where(
+                elig, jnp.clip(layer_of(profile, cum_h), 0,
+                               profile.cum_gflops.shape[0] - 1),
+                st["hop_layer"])
+            st["hop_stall"] = jnp.where(elig, 0, st["hop_stall"])
     st["tx_dst"] = jnp.where(elig, tgt, st["tx_dst"])
     st["tx_bits"] = jnp.where(elig, bits, st["tx_bits"])
     st["tx_cum"] = jnp.where(elig, cum_snap, st["tx_cum"])
     st["tx_created"] = jnp.where(elig, st["q_created"][rows, head],
                                  st["tx_created"])
-    st["tx_visited"] = jnp.where(elig[:, None],
-                                 st["q_visited"][rows, head],
-                                 st["tx_visited"])
+    with phase("visited"):
+        st["tx_visited"] = jnp.where(elig[:, None],
+                                     st["q_visited"][rows, head],
+                                     st["tx_visited"])
     st["tx_start"] = jnp.where(elig, t0, st["tx_start"])
     # i32 count: exact under any reduction order, so the in-scan sum
     # cannot drift across executor backends (swarmlint J001, §8.2)
@@ -97,14 +101,16 @@ def progress(st, cap, alive, cfg: SwarmConfig, t_now):
     tx_w = 10.0 ** (cfg.tx_power_dbm / 10.0) * 1e-3
     st = dict(st)
     if "hop_stall" in st:    # pending but not progressing: fault stall or
-        st["hop_stall"] = st["hop_stall"] + (   # post-arrival queue-wait
-            st["tx_active"] & (~live | pre_arrived)).astype(jnp.int32)
+        with phase("trace_capture"):          # post-arrival queue-wait
+            st["hop_stall"] = st["hop_stall"] + (
+                st["tx_active"] & (~live | pre_arrived)).astype(jnp.int32)
     st["tx_bits"] = jnp.where(flying, st["tx_bits"] - rate * tick,
                               st["tx_bits"])
     st["e_tx"] = st["e_tx"] + jnp.where(flying, tx_w * tick, 0.0)
     if "tx_energy" in st:    # attribute the airtime joules to the task
-        st["tx_energy"] = st["tx_energy"] + jnp.where(flying,
-                                                      tx_w * tick, 0.0)
+        with phase("trace_capture"):
+            st["tx_energy"] = st["tx_energy"] + jnp.where(
+                flying, tx_w * tick, 0.0)
     arrived = active & (st["tx_bits"] <= 0.0)
     # receiver contention: lowest-index origin wins per destination
     origin_rank = jnp.where(arrived, rows, INT_MAX)
@@ -122,8 +128,9 @@ def progress(st, cap, alive, cfg: SwarmConfig, t_now):
         jnp.where(deliver, rows, 0))                        # origin per dst
     cum_d = st["tx_cum"][inv]
     created_d = st["tx_created"][inv]
-    visited_d = st["tx_visited"][inv] | jax.nn.one_hot(
-        inv, n, dtype=bool)                                 # mark origin
+    with phase("visited"):
+        visited_d = st["tx_visited"][inv] | jax.nn.one_hot(
+            inv, n, dtype=bool)                             # mark origin
     if trace_record.hops_enabled(cfg):
         st = trace_record.write_hop_records(
             st, deliver, seq=st["hop_seq"], src=rows, dst=st["tx_dst"],

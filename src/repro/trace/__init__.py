@@ -25,7 +25,7 @@ heatmaps, energy-drain trajectories and imbalance indices, and
 latency into compute / queue-wait / airtime / fault-stall segments that
 sum back exactly (DESIGN.md §14.4) — ``segment_indices`` feeds the BENCH
 ``latency_segments`` payload and ``attribute`` names the segment that
-moved in a perf-gate regression.
+moved between two of them.
 
 Enabled by ``SwarmConfig.trace_capacity > 0`` (tasks),
 ``SwarmConfig.trace_hop_capacity > 0`` (hops) and

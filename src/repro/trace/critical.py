@@ -123,7 +123,7 @@ def segment_indices(dec: Mapping, hdec: Optional[Mapping] = None, *,
 def attribute(baseline: Mapping, current: Mapping,
               quantile: str = "p50") -> Optional[Dict]:
     """Name the segment that moved between two :func:`segment_indices`
-    payloads — the perf-gate attribution step (DESIGN.md §14.5).
+    payloads (DESIGN.md §14.4).
 
     Compares each segment's ``quantile`` entry and returns the largest
     absolute increase as ``{"segment", "baseline_s", "current_s",

@@ -46,6 +46,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
+from repro.obs.scopes import phase
 from repro.trace import schema
 
 
@@ -198,19 +199,21 @@ def _scatter_records(st, key_records, key_overflow, mask, seq, rows):
 def write_records(st, mask, *, seq, src, dst, created_t, completed_t,
                   exit_label, layers, hops, energy_j, tx_time_s):
     """Scatter one TaskRecord per ``mask`` lane into slot ``seq``."""
-    rows = schema.pack(seq, src, dst, created_t, completed_t, exit_label,
-                       layers, hops, energy_j, tx_time_s)
-    return _scatter_records(st, "trace_records", "trace_overflow", mask,
-                            seq, rows)
+    with phase("trace_capture"):
+        rows = schema.pack(seq, src, dst, created_t, completed_t, exit_label,
+                           layers, hops, energy_j, tx_time_s)
+        return _scatter_records(st, "trace_records", "trace_overflow", mask,
+                                seq, rows)
 
 
 def write_hop_records(st, mask, *, seq, src, dst, t_depart, t_arrive, bits,
                       boundary_layer, stall_ticks):
     """Scatter one HopRecord per ``mask`` lane into slot ``seq``."""
-    rows = schema.pack_hop(seq, src, dst, t_depart, t_arrive, bits,
-                           boundary_layer, stall_ticks)
-    return _scatter_records(st, "trace_hops", "trace_hop_overflow", mask,
-                            seq, rows)
+    with phase("trace_capture"):
+        rows = schema.pack_hop(seq, src, dst, t_depart, t_arrive, bits,
+                               boundary_layer, stall_ticks)
+        return _scatter_records(st, "trace_hops", "trace_hop_overflow", mask,
+                                seq, rows)
 
 
 def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
@@ -224,22 +227,24 @@ def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
     """
     from repro.swarm.queues import push      # deferred: queues ↔ trace
 
-    n = st["q_active"].shape[0]
-    has_free = ~jnp.all(st["q_active"], axis=1)
-    dropped = mask & ~has_free
-    st = push(st, mask, cum, created, visited,
-              extras={"src": src, "energy": energy, "txtime": txtime})
-    # seqs for the drops, after push consumed the accepted tasks' seqs
-    # (i32-pinned reductions: numpy-style widening under x64 would drift
-    # the seq-counter carry dtype — swarmlint J002)
-    drop_seq = st["seq_counter"] + jnp.cumsum(
-        dropped.astype(jnp.int32), dtype=jnp.int32) - 1
-    st = dict(st)
-    st["seq_counter"] = st["seq_counter"] + jnp.sum(
-        dropped.astype(jnp.int32), dtype=jnp.int32)
-    return write_records(
-        st, dropped, seq=drop_seq, src=src, dst=jnp.arange(n),
-        created_t=created, completed_t=t_now,
-        exit_label=jnp.int32(schema.DROPPED), layers=jnp.int32(0),
-        hops=jnp.sum(visited, axis=-1), energy_j=energy,
-        tx_time_s=txtime)
+    with phase("trace_capture"):
+        n = st["q_active"].shape[0]
+        has_free = ~jnp.all(st["q_active"], axis=1)
+        dropped = mask & ~has_free
+        st = push(st, mask, cum, created, visited,
+                  extras={"src": src, "energy": energy, "txtime": txtime})
+        # seqs for the drops, after push consumed the accepted tasks' seqs
+        # (i32-pinned reductions: numpy-style widening under x64 would drift
+        # the seq-counter carry dtype — swarmlint J002)
+        drop_seq = st["seq_counter"] + jnp.cumsum(
+            dropped.astype(jnp.int32), dtype=jnp.int32) - 1
+        st = dict(st)
+        st["seq_counter"] = st["seq_counter"] + jnp.sum(
+            dropped.astype(jnp.int32), dtype=jnp.int32)
+        with phase("visited"):
+            hops = jnp.sum(visited, axis=-1)
+        return write_records(
+            st, dropped, seq=drop_seq, src=src, dst=jnp.arange(n),
+            created_t=created, completed_t=t_now,
+            exit_label=jnp.int32(schema.DROPPED), layers=jnp.int32(0),
+            hops=hops, energy_j=energy, tx_time_s=txtime)

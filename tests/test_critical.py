@@ -1,13 +1,12 @@
 """Critical-path attribution + load-generator tests (DESIGN.md §14.2,
-§14.4-§14.5): per-task segment reconciliation against latency_s,
-stable key sets under degraded inputs, perf-gate segment attribution and
-host-class gating, arrival-process determinism, and the open-loop
-SLO smoke over the synthetic serve engine.
+§14.4): per-task segment reconciliation against latency_s,
+stable key sets under degraded inputs, segment attribution,
+arrival-process determinism, and the open-loop SLO smoke over the
+synthetic serve engine.
 """
 import numpy as np
 import pytest
 
-from benchmarks.perf_gate import attribute_failure, compare
 from repro.obs.loadgen import (SyntheticServeEngine, mmpp_arrivals,
                                poisson_arrivals, replay_arrivals,
                                run_open_loop)
@@ -115,47 +114,6 @@ def test_attribute_names_the_moved_segment():
     assert hit["delta_s"] == pytest.approx(1.0)
     assert attribute(base, base) is None              # nothing regressed
     assert attribute({}, {}) is None                  # nothing comparable
-
-
-# ---------------------------------------------------------------------------
-# perf gate: host classes, rel-tol, attribution lookup
-# ---------------------------------------------------------------------------
-
-def test_perf_gate_host_class_and_rel_tol():
-    base = {"s": {"pt": {"cached": False, "execute_s": 1.0,
-                         "host_class": "linux-x86_64-c8"}}}
-
-    def cur(ratio, hc):
-        return {"s": {"pt": {"cached": False, "execute_s": ratio,
-                             "host_class": hc}}}
-
-    _, _, failures = compare(base, cur(3.0, "linux-x86_64-c8"), 2.0, 0.0)
-    assert failures                                   # same class: gate
-    _, skipped, failures = compare(base, cur(3.0, "darwin-arm64-c10"),
-                                   2.0, 0.0)
-    assert not failures                               # cross class: warn
-    assert any("host classes differ" in why for _, why in skipped)
-    _, _, failures = compare(base, cur(2.4, "linux-x86_64-c8"),
-                             2.0, 0.0, rel_tol=0.5)
-    assert not failures                               # inside the slack
-    # untagged current gates as same-class (pre-tag baselines keep teeth)
-    untagged = {"s": {"pt": {"cached": False, "execute_s": 3.0}}}
-    _, _, failures = compare(base, untagged, 2.0, 0.0)
-    assert failures
-
-
-def test_perf_gate_attribution_lookup():
-    seg = segment_indices(decode(_task_rows()), tick_s=TICK,
-                          gflops_per_layer=0.2, capability_gflops=400.0)
-    worse = {k: (dict(v) if isinstance(v, dict) else v)
-             for k, v in seg.items()}
-    worse["airtime_s_quantiles"] = dict(seg["airtime_s_quantiles"])
-    worse["airtime_s_quantiles"]["p50"] += 0.7
-    base_doc = {"sweep:fig": {"points": {"pt": {"latency_segments": seg}}}}
-    cur_doc = {"sweep:fig": {"points": {"pt": {"latency_segments": worse}}}}
-    hit = attribute_failure(base_doc, cur_doc, "fig", "pt")
-    assert hit and hit["segment"] == "airtime_s"
-    assert attribute_failure({}, cur_doc, "fig", "pt") is None
 
 
 # ---------------------------------------------------------------------------

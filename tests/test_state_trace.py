@@ -3,7 +3,8 @@ and stride/subsample exactness, accounting against the scalar
 accumulators, bit-identical state buffers across all three executor
 backends, kill/resume preservation through the store (SweepInterrupted
 and a real SIGKILL'd spawned worker), report/export surfaces, the
-shared-schema serve gauges, and the profile spans the perf gate reads.
+shared-schema serve gauges, and the compile/execute spans of computed
+points.
 """
 import dataclasses
 import json
@@ -337,7 +338,7 @@ def test_serve_engine_steps_record_state():
 
 
 # ---------------------------------------------------------------------------
-# profile spans (the perf gate's input)
+# compile/execute spans
 # ---------------------------------------------------------------------------
 
 
@@ -354,25 +355,3 @@ def test_run_point_fills_spans_only_when_computing(tmp_path):
     hit = run_point(pt, store=store, spans=hit_spans)
     assert hit_spans == {}                  # a cache hit cost nothing
     assert sorted(hit) == sorted(first)     # identical metric surface
-
-
-def test_execute_emits_profile_rows_and_perf_gate_reads_them(tmp_path):
-    from benchmarks.perf_gate import compare
-    spec = SweepSpec.build("profile", CFG, strategies=(DISTRIBUTED,),
-                           num_runs=2)
-    res = execute(spec)
-    (label,) = res
-    assert res[label]["_wall_s"] > 0
-    assert res[label]["_execute_s"] is not None
-    base = {"profile": {label: {
-        "cached": False, "execute_s": float(res[label]["_execute_s"]),
-        "compile_s": float(res[label]["_compile_s"])}}}
-    checked, skipped, failures = compare(base, base, 2.0, 0.0)
-    assert not failures and len(checked) == 1
-    _, _, failures = compare(
-        base,
-        {"profile": {label: {
-            "cached": False,
-            "execute_s": 10 * float(res[label]["_execute_s"])}}},
-        2.0, 0.0)
-    assert failures                          # 10x regression trips the gate

@@ -2,8 +2,14 @@
 
 Each node owns ``Q = cfg.queue_slots`` slots; a task is (active, cum_gflops,
 created_t, seq, visited-set).  FIFO order is by global sequence number, so
-``head_slot`` is an argmin over active seqs — all ops are fixed-shape
-scatter/gathers that jit and vmap cleanly.
+``head_slot`` is an argmin over active seqs, and a push takes the first free
+slot, also an argmin.  The per-slot ``[n, Q]`` fields are addressed through a
+one-hot mask over the slot axis (``slot_mask``): a write is an elementwise
+select and a read a masked max-reduction, dense ops that fuse and update the
+loop carry in place, where an indexed scatter or gather per node runs as a
+serial loop over the nodes on the TPU.  The visited sets ``q_visited``
+``[n, Q, n]`` keep their indexed row write and row reads: a one-hot pass
+over Q would touch all ``n·Q·n`` entries to move one row of ``n`` per node.
 """
 from __future__ import annotations
 
@@ -24,6 +30,42 @@ def head_slot(st):
     return head, has
 
 
+def slot_mask(idx, Q: int) -> jax.Array:
+    """One-hot ``[n, Q]`` mask: True at slot ``idx[i]`` of row ``i``."""
+    return jnp.arange(Q, dtype=idx.dtype)[None, :] == idx[:, None]
+
+
+def slot_read(x, mask) -> jax.Array:
+    """``x[rows, idx]`` bit for bit, for ``mask = slot_mask(idx, Q)``.
+
+    A max over the row with every other slot set to the max's identity, so
+    the one selected value comes back unchanged (a float sum with zeros
+    would turn -0.0 into +0.0).
+    """
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        ident = -jnp.inf
+    else:
+        ident = jnp.iinfo(x.dtype).min
+    return jnp.max(jnp.where(mask, x, jnp.asarray(ident, x.dtype)), axis=1)
+
+
+def _col(val, dtype):
+    """``val`` ([n] or scalar) as a column that broadcasts over the slots."""
+    val = jnp.asarray(val, dtype)
+    return val[:, None] if val.ndim else val
+
+
+def slot_write(x, mask, val) -> jax.Array:
+    """``x`` with ``val[i]`` in the slots ``mask`` selects (≤ 1 per row)."""
+    return jnp.where(mask, _col(val, x.dtype), x)
+
+
+def slot_add(x, mask, val) -> jax.Array:
+    """``x.at[rows, idx].add(val)`` bit for bit: only the masked slot adds,
+    so an unmasked -0.0 stays -0.0."""
+    return jnp.where(mask, x + _col(val, x.dtype), x)
+
+
 def queued_gflops(st, profile: TaskProfile) -> jax.Array:
     """Remaining GFLOPs per node across all queued tasks (load metric T)."""
     rem = jnp.maximum(profile.total_gflops - st["q_cum"], 0.0)
@@ -33,8 +75,8 @@ def queued_gflops(st, profile: TaskProfile) -> jax.Array:
 def push(st, mask, cum, created, visited, extras=None):
     """Insert one task per node where mask; drops (with count) if full.
 
-    ``extras`` scatters additional per-task columns into ``q_<name>``
-    arrays alongside the core fields (the trace layer's attribution state,
+    ``extras`` writes additional per-task columns into ``q_<name>`` arrays
+    alongside the core fields (the trace layer's attribution state,
     ``repro.trace.record``); ``None`` leaves the state untouched beyond
     the core fields — the untraced path is byte-for-byte the historical
     one.
@@ -49,27 +91,17 @@ def push(st, mask, cum, created, visited, extras=None):
         # x64, which would drift the i32 seq fields' carry (swarmlint J002)
         seq = (st["seq_counter"]
                + jnp.cumsum(ok.astype(jnp.int32), dtype=jnp.int32) - 1)
+        put = slot_mask(free, Q) & ok[:, None]
         st = dict(st)
         for name, val in (extras or {}).items():
-            k = f"q_{name}"
+            st[f"q_{name}"] = slot_write(st[f"q_{name}"], put, val)
+        st["q_active"] = st["q_active"] | put
+        st["q_cum"] = slot_write(st["q_cum"], put, cum)
+        st["q_created"] = slot_write(st["q_created"], put, created)
+        st["q_seq"] = slot_write(st["q_seq"], put, seq)
+        with phase("visited"):
             # oob: `free` is an argmin over the slot axis, always in
             # [0, Q); drop mode is the .at[] default, never exercised (J003)
-            st[k] = st[k].at[rows, free].set(
-                jnp.where(ok, jnp.asarray(val, st[k].dtype),
-                          st[k][rows, free]))
-        # oob: same in-range `free` slot for every core-field scatter
-        st["q_active"] = st["q_active"].at[rows, free].set(
-            jnp.where(ok, True, st["q_active"][rows, free]))
-        st["q_cum"] = st["q_cum"].at[rows, free].set(
-            jnp.where(ok, cum, st["q_cum"][rows, free]))
-        # oob: in-range `free` (argmin), see above
-        st["q_created"] = st["q_created"].at[rows, free].set(
-            jnp.where(ok, created, st["q_created"][rows, free]))
-        # oob: in-range `free` (argmin), see above
-        st["q_seq"] = st["q_seq"].at[rows, free].set(
-            jnp.where(ok, seq, st["q_seq"][rows, free]))
-        with phase("visited"):
-            # oob: in-range `free` (argmin), see above
             st["q_visited"] = st["q_visited"].at[rows, free].set(
                 jnp.where(ok[:, None], visited,
                           st["q_visited"][rows, free]))
@@ -86,9 +118,7 @@ def pop_head(st, mask):
     """Deactivate the FIFO head where mask."""
     with phase("queues"):
         head, _ = head_slot(st)
-        rows = jnp.arange(st["q_active"].shape[0])
+        pop = slot_mask(head, st["q_active"].shape[1]) & mask[:, None]
         st = dict(st)
-        # oob: `head` is an argmin over the slot axis, in [0, Q) (J003)
-        st["q_active"] = st["q_active"].at[rows, head].set(
-            jnp.where(mask, False, st["q_active"][rows, head]))
+        st["q_active"] = st["q_active"] & ~pop
     return st

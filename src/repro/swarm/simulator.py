@@ -36,7 +36,8 @@ from repro.obs.scopes import phase
 from repro.swarm import transfer as transfer_mod
 from repro.swarm.channel import edge_rate, link_state, link_state_sparse
 from repro.swarm.neighbors import mask_neighbors, neighbor_lists
-from repro.swarm.queues import head_slot, push, queued_gflops
+from repro.swarm.queues import (head_slot, push, queued_gflops, slot_add,
+                                slot_mask, slot_read, slot_write)
 from repro.swarm.scenario import (burst_arrivals, get_channel,
                                   get_channel_edges, get_fault,
                                   get_mobility, mask_adjacency)
@@ -128,19 +129,17 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
     n, Q = st["q_active"].shape
     rows = jnp.arange(n)
     head, has = head_slot(st)
-    cur = st["q_cum"][rows, head]
+    at_head = slot_mask(head, Q)
+    cur = slot_read(st["q_cum"], at_head)
     rem = jnp.maximum(targets_cum - cur, 0.0)
     adv = jnp.where(has, jnp.minimum(budget, rem), 0.0)
     new_cum = cur + adv
     completed = has & (new_cum >= targets_cum - 1e-6)
-    lat = t_now - st["q_created"][rows, head]
+    lat = t_now - slot_read(st["q_created"], at_head)
     acc = exit_accuracy(st["xi_label"], cfg.exit_accuracy)
 
     st = dict(st)
-    # oob: `head` is queues.head_slot's argmin, always in [0, Q); drop
-    # mode is the .at[] default, never exercised (J003)
-    st["q_cum"] = st["q_cum"].at[rows, head].set(
-        jnp.where(has, new_cum, st["q_cum"][rows, head]))
+    st["q_cum"] = slot_write(st["q_cum"], at_head & has[:, None], new_cum)
     st["proc_gflops"] = st["proc_gflops"] + adv
     st["e_comp"] = st["e_comp"] + adv * eJ
     # dtype-pinned i32 count (bool sums widen to i64 under x64 — J002)
@@ -149,23 +148,21 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
     st["lat_sum"] = st["lat_sum"] + jnp.sum(jnp.where(completed, lat, 0.0))
     st["acc_sum"] = st["acc_sum"] + jnp.sum(jnp.where(completed, acc, 0.0))
     with phase("queues"):   # a completed head leaves its slot: a pop
-        # oob: in-range `head` (argmin), see the q_cum scatter above (J003)
-        st["q_active"] = st["q_active"].at[rows, head].set(
-            jnp.where(completed, False, st["q_active"][rows, head]))
+        st["q_active"] = st["q_active"] & ~(at_head & completed[:, None])
     if trace_record.enabled(cfg):
         with phase("trace_capture"):
-            # oob: in-range `head` (argmin); add-where-inactive is masked
-            # by adv == 0 on empty queues (J003)
-            st["q_energy"] = st["q_energy"].at[rows, head].add(adv * eJ)
+            # adding at an empty queue's slot 0 is harmless: adv == 0 there
+            st["q_energy"] = slot_add(st["q_energy"], at_head, adv * eJ)
             with phase("visited"):
                 hops = jnp.sum(st["q_visited"][rows, head], axis=-1)
             st = trace_record.write_records(
-                st, completed, seq=st["q_seq"][rows, head],
-                src=st["q_src"][rows, head], dst=rows,
-                created_t=st["q_created"][rows, head], completed_t=t_now,
-                exit_label=st["xi_label"], layers=st["xi_layers"],
-                hops=hops, energy_j=st["q_energy"][rows, head],
-                tx_time_s=st["q_txtime"][rows, head])
+                st, completed, seq=slot_read(st["q_seq"], at_head),
+                src=slot_read(st["q_src"], at_head), dst=rows,
+                created_t=slot_read(st["q_created"], at_head),
+                completed_t=t_now, exit_label=st["xi_label"],
+                layers=st["xi_layers"], hops=hops,
+                energy_j=slot_read(st["q_energy"], at_head),
+                tx_time_s=slot_read(st["q_txtime"], at_head))
     return st, budget - adv
 
 

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
 from repro.obs.scopes import phase
-from repro.swarm.queues import INT_MAX, head_slot, pop_head, push
+from repro.swarm.queues import (INT_MAX, head_slot, pop_head, push,
+                                slot_mask, slot_read)
 from repro.swarm.tasks import (TaskProfile, boundary_bits, layer_of,
                                snap_to_boundary)
 from repro.trace import record as trace_record
@@ -32,17 +33,19 @@ from repro.trace import record as trace_record
 def initiate(st, elig, tgt, t0, profile: TaskProfile):
     """Start transfers where ``elig``: pop the head task, discard partial-
     layer progress and stage the boundary activation for shipping."""
-    rows = jnp.arange(st["F"].shape[0])
+    n, Q = st["q_active"].shape
+    rows = jnp.arange(n)
     head, _ = head_slot(st)
-    cum_h = st["q_cum"][rows, head]
+    at_head = slot_mask(head, Q)
+    cum_h = slot_read(st["q_cum"], at_head)
     cum_snap = snap_to_boundary(profile, cum_h)
     bits = boundary_bits(profile, cum_h)
     st = dict(st)
     if "tx_src" in st:       # trace attribution rides along (DESIGN §10.2)
         with phase("trace_capture"):
             for f in ("src", "energy", "txtime"):
-                st[f"tx_{f}"] = jnp.where(elig, st[f"q_{f}"][rows, head],
-                                          st[f"tx_{f}"])
+                st[f"tx_{f}"] = jnp.where(
+                    elig, slot_read(st[f"q_{f}"], at_head), st[f"tx_{f}"])
     if "hop_seq" in st:      # hop stream: assign seqs at initiation (§10.5)
         with phase("trace_capture"):
             # i32-pinned reductions: numpy-style widening to i64 under x64
@@ -61,7 +64,7 @@ def initiate(st, elig, tgt, t0, profile: TaskProfile):
     st["tx_dst"] = jnp.where(elig, tgt, st["tx_dst"])
     st["tx_bits"] = jnp.where(elig, bits, st["tx_bits"])
     st["tx_cum"] = jnp.where(elig, cum_snap, st["tx_cum"])
-    st["tx_created"] = jnp.where(elig, st["q_created"][rows, head],
+    st["tx_created"] = jnp.where(elig, slot_read(st["q_created"], at_head),
                                  st["tx_created"])
     with phase("visited"):
         st["tx_visited"] = jnp.where(elig[:, None],

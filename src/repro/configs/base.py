@@ -357,6 +357,12 @@ class SwarmConfig:
     # task profile (illustrative detection CNN, DESIGN.md §3)
     task_layers: int = 60
     task_gflops_total: float = 12.0
+    # task mix (swarm/tasks.py): the named profiles the swarm's tasks split
+    # (cnn60 is the CNN above; vgg16, resnet50 are built from their layer
+    # tables) and each one's share of the arrivals.  One profile is the
+    # historical program: no per-task profile state exists.
+    task_profiles: Tuple[str, ...] = ("cnn60",)
+    task_mix: Tuple[float, ...] = (1.0,)
     # --- per-task telemetry (repro.trace, DESIGN.md §10) ---
     # > 0 enables in-scan TaskRecord capture: one fixed-width record per
     # completed/dropped task, scattered by global seq into a buffer of this

@@ -32,7 +32,7 @@ PHASES = ("init", "keys",
           "faults", "mobility", "channel", "neighbors", "phi_update",
           "decision", "early_exit", "initiate",
           "arrivals", "compute", "transfers", "queues",
-          "visited", "trace_capture", "summarize")
+          "visited", "task_profile", "trace_capture", "summarize")
 
 RUN_BATCH = "run_batch"     # StepTraceAnnotation, step_num = execution
 COMPILE = "compile"         # TraceAnnotation, only on an executable miss

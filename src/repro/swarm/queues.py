@@ -1,13 +1,15 @@
 """Struct-of-arrays task-queue ops (DESIGN.md §3.2).
 
 Each node owns ``Q = cfg.queue_slots`` slots; a task is (active, cum_gflops,
-created_t, seq, visited-set).  FIFO order is by global sequence number, so
-``head_slot`` is an argmin over active seqs, and a push takes the first free
-slot, also an argmin.  The per-slot ``[n, Q]`` fields are addressed through a
-one-hot mask over the slot axis (``slot_mask``): a write is an elementwise
-select and a read a masked max-reduction, dense ops that fuse and update the
-loop carry in place, where an indexed scatter or gather per node runs as a
-serial loop over the nodes on the TPU.  The visited sets ``q_visited``
+created_t, seq, visited-set), and under a task mix its profile id
+(``q_profile``, written through ``push``'s ``extras``).  FIFO order is by
+global sequence number, so ``head_slot`` is an argmin over active seqs, and
+a push takes the first free slot, also an argmin.  The per-slot ``[n, Q]``
+fields are addressed through a one-hot mask over the slot axis
+(``slot_mask``): a write is an elementwise select and a read a masked
+max-reduction, dense ops that fuse and update the loop carry in place,
+where an indexed scatter or gather per node runs as a serial loop over the
+nodes on the TPU.  The visited sets ``q_visited``
 ``[n, Q, n]`` keep their indexed row write and row reads: a one-hot pass
 over Q would touch all ``n·Q·n`` entries to move one row of ``n`` per node.
 """
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.scopes import phase
-from repro.swarm.tasks import TaskProfile
+from repro.swarm.tasks import ProfileMix, pick
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 
@@ -66,9 +68,15 @@ def slot_add(x, mask, val) -> jax.Array:
     return jnp.where(mask, x + _col(val, x.dtype), x)
 
 
-def queued_gflops(st, profile: TaskProfile) -> jax.Array:
-    """Remaining GFLOPs per node across all queued tasks (load metric T)."""
-    rem = jnp.maximum(profile.total_gflops - st["q_cum"], 0.0)
+def queued_gflops(st, profile) -> jax.Array:
+    """Remaining GFLOPs per node across all queued tasks (load metric T),
+    each task against its own profile's total under a ``ProfileMix``."""
+    if isinstance(profile, ProfileMix):
+        with phase("task_profile"):
+            total = pick(profile.total_gflops, st["q_profile"])
+    else:
+        total = profile.total_gflops
+    rem = jnp.maximum(total - st["q_cum"], 0.0)
     return jnp.sum(jnp.where(st["q_active"], rem, 0.0), axis=1)
 
 

@@ -41,7 +41,8 @@ from repro.swarm.queues import (head_slot, push, queued_gflops, slot_add,
 from repro.swarm.scenario import (burst_arrivals, get_channel,
                                   get_channel_edges, get_fault,
                                   get_mobility, mask_adjacency)
-from repro.swarm.tasks import TaskProfile, make_profile
+from repro.swarm.tasks import (PROFILE_KEY, ProfileMix, draw_profiles,
+                               is_mix, make_profile, pick)
 from repro.trace import record as trace_record
 
 BIG = 1e30
@@ -115,6 +116,13 @@ def init_state(key, cfg: SwarmConfig, n: int) -> Dict:
         **trace_record.init_trace(cfg, n),
         **trace_record.init_hops(cfg, n),
         **trace_record.init_state_stream(cfg, n),
+        # per-task profile ids and completions per profile: only under a
+        # task mix, so a one-profile state is exactly the historical one
+        **({"q_profile": jnp.zeros((n, Q), jnp.int32),
+            "tx_profile": jnp.zeros((n,), jnp.int32),
+            "done_by_profile": jnp.zeros((len(cfg.task_profiles),),
+                                         jnp.int32)}
+           if is_mix(cfg) else {}),
     }
 
 
@@ -123,13 +131,20 @@ def init_state(key, cfg: SwarmConfig, n: int) -> Dict:
 # ---------------------------------------------------------------------------
 
 
-def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
-    """Advance each node's head task by up to `budget` GFLOPs."""
+def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig,
+                  mix: ProfileMix | None = None):
+    """Advance each node's head task by up to `budget` GFLOPs: to
+    ``targets_cum``, or under a task mix to the full depth of the head
+    task's own profile."""
     eJ = cfg.energy_per_gflop_j
     n, Q = st["q_active"].shape
     rows = jnp.arange(n)
     head, has = head_slot(st)
     at_head = slot_mask(head, Q)
+    if mix is not None:
+        with phase("task_profile"):
+            pid_h = slot_read(st["q_profile"], at_head)
+            targets_cum = pick(mix.done_gflops, pid_h)
     cur = slot_read(st["q_cum"], at_head)
     rem = jnp.maximum(targets_cum - cur, 0.0)
     adv = jnp.where(has, jnp.minimum(budget, rem), 0.0)
@@ -147,6 +162,11 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
                                                   dtype=jnp.int32)
     st["lat_sum"] = st["lat_sum"] + jnp.sum(jnp.where(completed, lat, 0.0))
     st["acc_sum"] = st["acc_sum"] + jnp.sum(jnp.where(completed, acc, 0.0))
+    if mix is not None:
+        with phase("task_profile"):
+            st["done_by_profile"] = st["done_by_profile"] + jnp.sum(
+                (pid_h[:, None] == jnp.arange(len(mix.names)))
+                & completed[:, None], axis=0, dtype=jnp.int32)
     with phase("queues"):   # a completed head leaves its slot: a pop
         st["q_active"] = st["q_active"] & ~(at_head & completed[:, None])
     if trace_record.enabled(cfg):
@@ -166,35 +186,46 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig):
     return st, budget - adv
 
 
-def _tick(st, key, cfg: SwarmConfig, profile: TaskProfile, cap, alive,
-          t_now):
+def _tick(st, key, cfg: SwarmConfig, profile, cap, alive, t_now):
     n = st["F"].shape[0]
     tick = cfg.tick_s
+    mix = profile if isinstance(profile, ProfileMix) else None
 
-    # (a) Markov-modulated arrivals (down nodes don't generate)
+    # (a) Markov-modulated arrivals (down nodes don't generate); under a
+    #     task mix each arrival draws its profile off a key folded from the
+    #     tick key, which leaves the arrival draws as they were
     st = dict(st)
     with phase("arrivals"):
         st["burst_on"], arrive = burst_arrivals(st["burst_on"], key, cfg)
         arrive = arrive & alive
+        pid = None
+        if mix is not None:
+            with phase("task_profile"):
+                pid = draw_profiles(jax.random.fold_in(key, PROFILE_KEY),
+                                    mix, n)
         if trace_record.enabled(cfg):
             st = trace_record.traced_push(
                 st, arrive, jnp.zeros((n,), jnp.float32),
                 jnp.full((n,), t_now), jnp.zeros((n, n), bool),
                 src=jnp.arange(n), energy=0.0,
-                txtime=0.0, t_now=t_now, cfg=cfg)
+                txtime=0.0, t_now=t_now, cfg=cfg, profile=pid)
         else:
             st = push(st, arrive, jnp.zeros((n,), jnp.float32),
-                      jnp.full((n,), t_now), jnp.zeros((n, n), bool))
+                      jnp.full((n,), t_now), jnp.zeros((n, n), bool),
+                      None if pid is None else {"profile": pid})
         st["gen_count"] = st["gen_count"] + jnp.sum(arrive, dtype=jnp.int32)
 
     # (b) compute (budget cascade x2: finish a task and start the next;
     #     down nodes hold their queues but burn no cycles)
     with phase("compute"):
-        targets = profile.cum_gflops[jnp.clip(st["xi_layers"], 0,
-                                              profile.gflops.shape[0])]
+        if mix is None:
+            targets = profile.cum_gflops[jnp.clip(st["xi_layers"], 0,
+                                                  profile.gflops.shape[0])]
+        else:
+            targets = None          # each head task's own full depth
         budget = jnp.where(alive, st["F"] * tick, 0.0)
         for _ in range(2):
-            st, budget = _compute_pass(st, budget, targets, t_now, cfg)
+            st, budget = _compute_pass(st, budget, targets, t_now, cfg, mix)
 
     # (c) transfer progress + delivery
     with phase("transfers"):
@@ -325,8 +356,22 @@ def _strategy_decision_sparse(st, strategy, adj_e, nbr, d_tx_e, T, key,
     return do, tgt, phi
 
 
-def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
-           profile: TaskProfile):
+def _transfer_bits_per_gflop(st, profile):
+    """Bits per GFLOP of the transfer-delay estimate d_tx: the profile's,
+    or under a task mix a column of the head task's profile per node (the
+    share-weighted mean where the queue is empty)."""
+    if not isinstance(profile, ProfileMix):
+        return profile.bits_per_gflop
+    with phase("task_profile"):
+        head, has = head_slot(st)
+        pid_h = slot_read(st["q_profile"],
+                          slot_mask(head, st["q_active"].shape[1]))
+        bpg = jnp.where(has, pick(profile.bits_per_gflop, pid_h),
+                        jnp.float32(profile.idle_bits_per_gflop))
+        return bpg[:, None]
+
+
+def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
     t0 = epoch_idx.astype(jnp.float32) * cfg.decision_period_s
     # kd/kt reproduce the pre-engine key streams exactly; scenario keys are
     # folded off the epoch key so the default scenario stays bit-identical
@@ -348,6 +393,7 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
         st["mob"], pos = get_mobility(cfg).step(st["mob"], k_mob, cfg, t0)
     with phase("decision"):
         T = queued_gflops(st, profile)
+        bpg = _transfer_bits_per_gflop(st, profile)
     sparse = cfg.neighbor_mode == "sparse"
     if sparse:
         edge_fn = get_channel_edges(cfg)
@@ -357,7 +403,7 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
         with phase("channel"):
             adj_e, cap_e = link_state_sparse(pos, nbr, valid, cfg, key=k_ch,
                                              pathloss_fn=edge_fn)
-            d_tx_e = jnp.where(adj_e, profile.bits_per_gflop / cap_e, BIG)
+            d_tx_e = jnp.where(adj_e, bpg / cap_e, BIG)
         with phase("decision"):
             do, tgt, phi = _strategy_decision_sparse(
                 st, strategy, adj_e, nbr, d_tx_e, T, kd, cfg)
@@ -366,7 +412,7 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig,
             adj, cap = link_state(pos, cfg, key=k_ch,
                                   pathloss_fn=get_channel(cfg))
             adj = mask_adjacency(adj, st["alive"])
-            d_tx = jnp.where(adj, profile.bits_per_gflop / cap, BIG)
+            d_tx = jnp.where(adj, bpg / cap, BIG)
         with phase("decision"):
             do, tgt, phi = _strategy_decision(st, strategy, adj, d_tx, T,
                                               kd, cfg)
@@ -447,15 +493,19 @@ def run_sim(key, cfg: SwarmConfig, strategy, n: int | None = None) -> Dict:
         return summarize(st, cfg, profile)
 
 
-def summarize(st, cfg: SwarmConfig, profile: TaskProfile) -> Dict:
+def summarize(st, cfg: SwarmConfig, profile) -> Dict:
     # the i32 event counters re-enter float land here, outside the scan:
     # counts are exact in f32 up to 2^24, so every reported metric is
     # bit-identical to the historical f32-accumulator values
     done_f = st["done_count"].astype(jnp.float32)
     done = jnp.maximum(done_f, 1.0)
     rem_q = queued_gflops(st, profile)
-    rem_tx = jnp.where(st["tx_active"],
-                       profile.total_gflops - st["tx_cum"], 0.0)
+    if isinstance(profile, ProfileMix):
+        with phase("task_profile"):
+            tx_total = pick(profile.total_gflops, st["tx_profile"])
+    else:
+        tx_total = profile.total_gflops
+    rem_tx = jnp.where(st["tx_active"], tx_total - st["tx_cum"], 0.0)
     # Jain fairness over capability-normalized processed GFLOPs (Fig. 4d)
     x = st["proc_gflops"] / st["F"]
     jain = (jnp.sum(x) ** 2) / (x.shape[0] * jnp.sum(x * x) + 1e-12)
@@ -485,6 +535,10 @@ def summarize(st, cfg: SwarmConfig, profile: TaskProfile) -> Dict:
         "dropped": st["drop_count"].astype(jnp.float32),
         "fom": fom,
     }
+    if isinstance(profile, ProfileMix):
+        for p, name in enumerate(profile.names):
+            out[f"completed_{name}"] = \
+                st["done_by_profile"][p].astype(jnp.float32)
     if trace_record.enabled(cfg):
         # per-task telemetry rides next to the scalar metrics; downstream
         # consumers key off the trace_ prefix (report skips ci95 for them,

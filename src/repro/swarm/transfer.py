@@ -25,22 +25,27 @@ from repro.configs.base import SwarmConfig
 from repro.obs.scopes import phase
 from repro.swarm.queues import (INT_MAX, head_slot, pop_head, push,
                                 slot_mask, slot_read)
-from repro.swarm.tasks import (TaskProfile, boundary_bits, layer_of,
-                               snap_to_boundary)
+from repro.swarm.tasks import boundary_bits, layer_of, snap_to_boundary
 from repro.trace import record as trace_record
 
 
-def initiate(st, elig, tgt, t0, profile: TaskProfile):
+def initiate(st, elig, tgt, t0, profile):
     """Start transfers where ``elig``: pop the head task, discard partial-
-    layer progress and stage the boundary activation for shipping."""
+    layer progress and stage the boundary activation for shipping (of the
+    task's own profile, which travels with it, under a task mix)."""
     n, Q = st["q_active"].shape
     rows = jnp.arange(n)
     head, _ = head_slot(st)
     at_head = slot_mask(head, Q)
     cum_h = slot_read(st["q_cum"], at_head)
-    cum_snap = snap_to_boundary(profile, cum_h)
-    bits = boundary_bits(profile, cum_h)
+    pid_h = None
     st = dict(st)
+    if "q_profile" in st:
+        with phase("task_profile"):
+            pid_h = slot_read(st["q_profile"], at_head)
+            st["tx_profile"] = jnp.where(elig, pid_h, st["tx_profile"])
+    cum_snap = snap_to_boundary(profile, cum_h, pid_h)
+    bits = boundary_bits(profile, cum_h, pid_h)
     if "tx_src" in st:       # trace attribution rides along (DESIGN §10.2)
         with phase("trace_capture"):
             for f in ("src", "energy", "txtime"):
@@ -57,8 +62,8 @@ def initiate(st, elig, tgt, t0, profile: TaskProfile):
                 elig.astype(jnp.int32), dtype=jnp.int32)
             st["hop_bits"] = jnp.where(elig, bits, st["hop_bits"])
             st["hop_layer"] = jnp.where(
-                elig, jnp.clip(layer_of(profile, cum_h), 0,
-                               profile.cum_gflops.shape[0] - 1),
+                elig, jnp.clip(layer_of(profile, cum_h, pid_h), 0,
+                               profile.cum_gflops.shape[-1] - 1),
                 st["hop_layer"])
             st["hop_stall"] = jnp.where(elig, 0, st["hop_stall"])
     st["tx_dst"] = jnp.where(elig, tgt, st["tx_dst"])
@@ -134,6 +139,10 @@ def progress(st, cap, alive, cfg: SwarmConfig, t_now):
     with phase("visited"):
         visited_d = st["tx_visited"][inv] | jax.nn.one_hot(
             inv, n, dtype=bool)                             # mark origin
+    profile_d = None
+    if "tx_profile" in st:
+        with phase("task_profile"):
+            profile_d = st["tx_profile"][inv]
     if trace_record.hops_enabled(cfg):
         st = trace_record.write_hop_records(
             st, deliver, seq=st["hop_seq"], src=rows, dst=st["tx_dst"],
@@ -145,9 +154,10 @@ def progress(st, cap, alive, cfg: SwarmConfig, t_now):
             src=st["tx_src"][inv], energy=st["tx_energy"][inv],
             txtime=st["tx_txtime"][inv] + jnp.where(
                 dst_mask, t_now - st["tx_start"][inv], 0.0),
-            t_now=t_now, cfg=cfg)
+            t_now=t_now, cfg=cfg, profile=profile_d)
     else:
-        st = push(st, dst_mask, cum_d, created_d, visited_d)
+        st = push(st, dst_mask, cum_d, created_d, visited_d,
+                  None if profile_d is None else {"profile": profile_d})
     st["tx_active"] = st["tx_active"] & ~deliver
     # i32 count (see tx_count in initiate); tx_time_sum below stays a
     # float accumulator and is baselined under J001 with its rationale

@@ -217,8 +217,9 @@ def write_hop_records(st, mask, *, seq, src, dst, t_depart, t_arrive, bits,
 
 
 def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
-                t_now, cfg: SwarmConfig):
-    """``queues.push`` plus attribution carry and drop records.
+                t_now, cfg: SwarmConfig, profile=None):
+    """``queues.push`` plus attribution carry and drop records (and the
+    task's profile id under a task mix).
 
     Tasks that find no free slot are dropped by ``push`` (counted in
     ``drop_count``); under tracing they additionally consume a seq — the
@@ -231,8 +232,10 @@ def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
         n = st["q_active"].shape[0]
         has_free = ~jnp.all(st["q_active"], axis=1)
         dropped = mask & ~has_free
-        st = push(st, mask, cum, created, visited,
-                  extras={"src": src, "energy": energy, "txtime": txtime})
+        extras = {"src": src, "energy": energy, "txtime": txtime}
+        if profile is not None:
+            extras["profile"] = profile
+        st = push(st, mask, cum, created, visited, extras=extras)
         # seqs for the drops, after push consumed the accepted tasks' seqs
         # (i32-pinned reductions: numpy-style widening under x64 would drift
         # the seq-counter carry dtype — swarmlint J002)

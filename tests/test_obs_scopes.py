@@ -20,7 +20,7 @@ from repro.swarm import DISTRIBUTED
 BASE = dataclasses.replace(SwarmConfig(), num_workers=8, sim_time_s=1.0)
 RUNS = 3
 # dense at the defaults; sparse with the early exit on; every trace stream
-# with Markov churn: between them every phase has ops somewhere
+# with Markov churn; a task mix: between them every phase has ops somewhere
 CONFIGS = {
     "dense": BASE,
     "sparse": dataclasses.replace(BASE, num_workers=12,
@@ -30,11 +30,16 @@ CONFIGS = {
                                   trace_capacity=256,
                                   trace_hop_capacity=256,
                                   trace_state_every=2),
+    "mix": dataclasses.replace(BASE, task_profiles=("vgg16", "resnet50"),
+                               task_mix=(0.5, 0.5)),
 }
 # phases whose feature the configuration turns off, so that they run no op
-OFF = {"dense": {"faults", "neighbors", "early_exit", "trace_capture"},
-       "sparse": {"faults", "trace_capture"},
-       "traced": {"neighbors", "early_exit"}}
+# (the per-task profile lookups run only under a task mix)
+OFF = {"dense": {"faults", "neighbors", "early_exit", "trace_capture",
+                 "task_profile"},
+       "sparse": {"faults", "trace_capture", "task_profile"},
+       "traced": {"neighbors", "early_exit", "task_profile"},
+       "mix": {"faults", "neighbors", "early_exit", "trace_capture"}}
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))

@@ -9,9 +9,12 @@ fields are addressed through a one-hot mask over the slot axis
 (``slot_mask``): a write is an elementwise select and a read a masked
 max-reduction, dense ops that fuse and update the loop carry in place,
 where an indexed scatter or gather per node runs as a serial loop over the
-nodes on the TPU.  The visited sets ``q_visited``
-``[n, Q, n]`` keep their indexed row write and row reads: a one-hot pass
-over Q would touch all ``n·Q·n`` entries to move one row of ``n`` per node.
+nodes on the TPU.
+
+A task's visited set is packed into ``W = ⌈n / 32⌉`` ``uint32`` words, node
+``j`` at bit ``j % 32`` of word ``j // 32``: ``q_visited`` is
+``[W, n, Q]``, one ``[n, Q]`` word plane per word, so each plane is read
+and written through the same slot mask as the other fields.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ from repro.obs.scopes import phase
 from repro.swarm.tasks import ProfileMix, pick
 
 INT_MAX = jnp.iinfo(jnp.int32).max
+WORD = 32                     # nodes per packed visited-set word
 
 
 def head_slot(st):
@@ -68,6 +72,48 @@ def slot_add(x, mask, val) -> jax.Array:
     return jnp.where(mask, x + _col(val, x.dtype), x)
 
 
+def visited_words(n: int) -> int:
+    """Words of one packed visited set over ``n`` nodes."""
+    return -(-n // WORD)
+
+
+def head_visited(q_visited, mask) -> jax.Array:
+    """``uint32 [W, n]``: the visited words of the slots ``mask`` selects
+    (one per row), read plane by plane like any slot field."""
+    return jax.vmap(slot_read, in_axes=(0, None))(q_visited, mask)
+
+
+def node_bits(ids, W: int) -> jax.Array:
+    """``uint32 [W, ...]``: the one-node sets ``{ids}``, packed."""
+    ids = jnp.asarray(ids, jnp.int32)
+    bit = jnp.left_shift(jnp.uint32(1), (ids % WORD).astype(jnp.uint32))
+    word = jnp.arange(W, dtype=jnp.int32).reshape((W,) + (1,) * ids.ndim)
+    return jnp.where(word == ids // WORD, bit, jnp.uint32(0))
+
+
+def unpack_visited(words, n: int) -> jax.Array:
+    """``bool [..., n]``: the sets held by ``words`` ``[W, ...]``."""
+    shift = jnp.arange(WORD, dtype=jnp.uint32).reshape(
+        (WORD,) + (1,) * (words.ndim - 1))
+    bits = (words[:, None] >> shift) & jnp.uint32(1)        # [W, 32, ...]
+    bits = bits.reshape((-1,) + words.shape[1:])[:n]
+    return jnp.moveaxis(bits, 0, -1).astype(bool)
+
+
+def has_node(words, ids) -> jax.Array:
+    """``bool [n, K]``: whether set ``i`` of ``words`` ``[W, n]`` holds
+    node ``ids[i, k]`` (a bit test, no unpacking)."""
+    ids = jnp.asarray(ids, jnp.int32)
+    rows = jnp.arange(ids.shape[0], dtype=jnp.int32)[:, None]
+    w = words[ids // WORD, rows]
+    return ((w >> (ids % WORD).astype(jnp.uint32)) & jnp.uint32(1)) == 1
+
+
+def hop_count(words) -> jax.Array:
+    """``int32``: the size of each packed set (a popcount over the words)."""
+    return jnp.sum(jax.lax.population_count(words), axis=0, dtype=jnp.int32)
+
+
 def queued_gflops(st, profile) -> jax.Array:
     """Remaining GFLOPs per node across all queued tasks (load metric T),
     each task against its own profile's total under a ``ProfileMix``."""
@@ -83,6 +129,7 @@ def queued_gflops(st, profile) -> jax.Array:
 def push(st, mask, cum, created, visited, extras=None):
     """Insert one task per node where mask; drops (with count) if full.
 
+    ``visited`` holds each task's packed set, ``uint32 [W, n]``.
     ``extras`` writes additional per-task columns into ``q_<name>`` arrays
     alongside the core fields (the trace layer's attribution state,
     ``repro.trace.record``); ``None`` leaves the state untouched beyond
@@ -90,11 +137,10 @@ def push(st, mask, cum, created, visited, extras=None):
     one.
     """
     with phase("queues"):
-        n, Q = st["q_active"].shape
+        Q = st["q_active"].shape[1]
         free = jnp.argmin(st["q_active"], axis=1)          # first False slot
         has_free = ~jnp.all(st["q_active"], axis=1)
         ok = mask & has_free
-        rows = jnp.arange(n)
         # dtype pins: integer cumsum/sum follow numpy and widen to i64 under
         # x64, which would drift the i32 seq fields' carry (swarmlint J002)
         seq = (st["seq_counter"]
@@ -108,11 +154,8 @@ def push(st, mask, cum, created, visited, extras=None):
         st["q_created"] = slot_write(st["q_created"], put, created)
         st["q_seq"] = slot_write(st["q_seq"], put, seq)
         with phase("visited"):
-            # oob: `free` is an argmin over the slot axis, always in
-            # [0, Q); drop mode is the .at[] default, never exercised (J003)
-            st["q_visited"] = st["q_visited"].at[rows, free].set(
-                jnp.where(ok[:, None], visited,
-                          st["q_visited"][rows, free]))
+            st["q_visited"] = jax.vmap(slot_write, in_axes=(0, None, 0))(
+                st["q_visited"], put, visited)
         st["seq_counter"] = st["seq_counter"] + jnp.sum(
             ok.astype(jnp.int32), dtype=jnp.int32)
         # i32 count: exact under any reduction order, so the in-scan sum

@@ -36,8 +36,10 @@ from repro.obs.scopes import phase
 from repro.swarm import transfer as transfer_mod
 from repro.swarm.channel import edge_rate, link_state, link_state_sparse
 from repro.swarm.neighbors import mask_neighbors, neighbor_lists
-from repro.swarm.queues import (head_slot, push, queued_gflops, slot_add,
-                                slot_mask, slot_read, slot_write)
+from repro.swarm.queues import (has_node, head_slot, head_visited,
+                                hop_count, push, queued_gflops, slot_add,
+                                slot_mask, slot_read, slot_write,
+                                unpack_visited, visited_words)
 from repro.swarm.scenario import (burst_arrivals, get_channel,
                                   get_channel_edges, get_fault,
                                   get_mobility, mask_adjacency)
@@ -77,7 +79,8 @@ def init_state(key, cfg: SwarmConfig, n: int) -> Dict:
         "q_cum": jnp.zeros((n, Q), jnp.float32),
         "q_created": jnp.zeros((n, Q), jnp.float32),
         "q_seq": jnp.zeros((n, Q), jnp.int32),
-        "q_visited": jnp.zeros((n, Q, n), bool),
+        # packed visited sets: bit j % 32 of word j // 32 (queues.py)
+        "q_visited": jnp.zeros((visited_words(n), n, Q), jnp.uint32),
         "seq_counter": jnp.int32(0),
         # single outgoing transfer per node (§3.2)
         "tx_active": jnp.zeros((n,), bool),
@@ -85,7 +88,7 @@ def init_state(key, cfg: SwarmConfig, n: int) -> Dict:
         "tx_bits": jnp.zeros((n,), jnp.float32),
         "tx_cum": jnp.zeros((n,), jnp.float32),
         "tx_created": jnp.zeros((n,), jnp.float32),
-        "tx_visited": jnp.zeros((n, n), bool),
+        "tx_visited": jnp.zeros((visited_words(n), n), jnp.uint32),
         "tx_start": jnp.zeros((n,), jnp.float32),
         # protocol state
         "phi": F,
@@ -174,7 +177,7 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig,
             # adding at an empty queue's slot 0 is harmless: adv == 0 there
             st["q_energy"] = slot_add(st["q_energy"], at_head, adv * eJ)
             with phase("visited"):
-                hops = jnp.sum(st["q_visited"][rows, head], axis=-1)
+                hops = hop_count(head_visited(st["q_visited"], at_head))
             st = trace_record.write_records(
                 st, completed, seq=slot_read(st["q_seq"], at_head),
                 src=slot_read(st["q_src"], at_head), dst=rows,
@@ -203,15 +206,16 @@ def _tick(st, key, cfg: SwarmConfig, profile, cap, alive, t_now):
             with phase("task_profile"):
                 pid = draw_profiles(jax.random.fold_in(key, PROFILE_KEY),
                                     mix, n)
+        fresh = jnp.zeros_like(st["tx_visited"])     # no node visited yet
         if trace_record.enabled(cfg):
             st = trace_record.traced_push(
                 st, arrive, jnp.zeros((n,), jnp.float32),
-                jnp.full((n,), t_now), jnp.zeros((n, n), bool),
+                jnp.full((n,), t_now), fresh,
                 src=jnp.arange(n), energy=0.0,
                 txtime=0.0, t_now=t_now, cfg=cfg, profile=pid)
         else:
             st = push(st, arrive, jnp.zeros((n,), jnp.float32),
-                      jnp.full((n,), t_now), jnp.zeros((n, n), bool),
+                      jnp.full((n,), t_now), fresh,
                       None if pid is None else {"profile": pid})
         st["gen_count"] = st["gen_count"] + jnp.sum(arrive, dtype=jnp.int32)
 
@@ -242,7 +246,6 @@ def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
     n = st["F"].shape[0]
     k1, k2, k3 = jax.random.split(key, 3)
     head, has = head_slot(st)
-    rows = jnp.arange(n)
     has_nbr = jnp.any(adj, axis=1)
 
     # ---- Distributed (ours): Eqs. 10-13, kernel-dispatched ----------------
@@ -272,7 +275,8 @@ def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
 
     # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
     with phase("visited"):
-        visited_head = st["q_visited"][rows, head]          # [N, N]
+        visited_head = unpack_visited(head_visited(
+            st["q_visited"], slot_mask(head, st["q_active"].shape[1])), n)
     amask = adj & ~visited_head
     a_has = jnp.any(amask, axis=1)
     a_tgt = jnp.argmax(jnp.where(amask, jax.random.gumbel(k3, (n, n)), -BIG),
@@ -332,11 +336,11 @@ def _strategy_decision_sparse(st, strategy, adj_e, nbr, d_tx_e, T, key,
     random_ = (r_do, r_tgt)
 
     # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
-    # the visited sets stay dense [N, Q, N] (a bitset redesign is ROADMAP
-    # work); the epoch cost here is only the [N, K] gather of head rows
+    # a bit test of the head task's packed set at the K neighbour ids: an
+    # [N, K] gather of words, never an [N, N] row
     with phase("visited"):
-        visited_head = st["q_visited"][rows, head]          # [N, N]
-        visited_nbr = visited_head[rows[:, None], nbr]      # [N, K]
+        visited_nbr = has_node(head_visited(
+            st["q_visited"], slot_mask(head, st["q_active"].shape[1])), nbr)
     amask = adj_e & ~visited_nbr
     a_has = jnp.any(amask, axis=1)
     a_tgt = nbr[rows, jnp.argmax(

@@ -18,13 +18,13 @@ alongside endpoint-down fault stalls.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
 from repro.obs.scopes import phase
-from repro.swarm.queues import (INT_MAX, head_slot, pop_head, push,
-                                slot_mask, slot_read)
+from repro.swarm.queues import (INT_MAX, head_slot, head_visited,
+                                node_bits, pop_head, push, slot_mask,
+                                slot_read)
 from repro.swarm.tasks import boundary_bits, layer_of, snap_to_boundary
 from repro.trace import record as trace_record
 
@@ -33,8 +33,7 @@ def initiate(st, elig, tgt, t0, profile):
     """Start transfers where ``elig``: pop the head task, discard partial-
     layer progress and stage the boundary activation for shipping (of the
     task's own profile, which travels with it, under a task mix)."""
-    n, Q = st["q_active"].shape
-    rows = jnp.arange(n)
+    Q = st["q_active"].shape[1]
     head, _ = head_slot(st)
     at_head = slot_mask(head, Q)
     cum_h = slot_read(st["q_cum"], at_head)
@@ -72,8 +71,8 @@ def initiate(st, elig, tgt, t0, profile):
     st["tx_created"] = jnp.where(elig, slot_read(st["q_created"], at_head),
                                  st["tx_created"])
     with phase("visited"):
-        st["tx_visited"] = jnp.where(elig[:, None],
-                                     st["q_visited"][rows, head],
+        st["tx_visited"] = jnp.where(elig, head_visited(st["q_visited"],
+                                                        at_head),
                                      st["tx_visited"])
     st["tx_start"] = jnp.where(elig, t0, st["tx_start"])
     # i32 count: exact under any reduction order, so the in-scan sum
@@ -136,9 +135,9 @@ def progress(st, cap, alive, cfg: SwarmConfig, t_now):
         jnp.where(deliver, rows, 0))                        # origin per dst
     cum_d = st["tx_cum"][inv]
     created_d = st["tx_created"][inv]
-    with phase("visited"):
-        visited_d = st["tx_visited"][inv] | jax.nn.one_hot(
-            inv, n, dtype=bool)                             # mark origin
+    with phase("visited"):          # the origin's set, plus the origin
+        visited_d = st["tx_visited"][:, inv] | node_bits(
+            inv, st["tx_visited"].shape[0])
     profile_d = None
     if "tx_profile" in st:
         with phase("task_profile"):

@@ -226,7 +226,8 @@ def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
     record keyspace covers every task that ever *finished*, completed or
     not — and scatter a ``DROPPED`` record stamped at ``t_now``.
     """
-    from repro.swarm.queues import push      # deferred: queues ↔ trace
+    # deferred: queues ↔ trace
+    from repro.swarm.queues import hop_count, push
 
     with phase("trace_capture"):
         n = st["q_active"].shape[0]
@@ -245,7 +246,7 @@ def traced_push(st, mask, cum, created, visited, *, src, energy, txtime,
         st["seq_counter"] = st["seq_counter"] + jnp.sum(
             dropped.astype(jnp.int32), dtype=jnp.int32)
         with phase("visited"):
-            hops = jnp.sum(visited, axis=-1)
+            hops = hop_count(visited)
         return write_records(
             st, dropped, seq=drop_seq, src=src, dst=jnp.arange(n),
             created_t=created, completed_t=t_now,

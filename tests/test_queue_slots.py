@@ -6,7 +6,9 @@ formulation it replaced: ``x[rows, idx]`` gathers and ``x.at[rows, idx]``
 scatters.  Both must agree bit for bit on every field of the state, for
 ``push`` (with and without trace extras), ``pop_head``, the compute pass and
 ``transfer.initiate``, on full queues, empty queues, all-false masks and
-under ``vmap``.
+under ``vmap``.  The reference keeps the visited sets as the bool
+``[n, Q, n]`` bitmap, unpacking and packing the state's words around it
+(``tests/test_visited_bits.py``).
 """
 import dataclasses
 
@@ -14,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_visited_bits import indexed_operand_shapes, pack, unpack
 
 from repro.configs.base import SwarmConfig
 from repro.swarm import queues, transfer
@@ -49,8 +52,9 @@ def ref_push(st, mask, cum, created, visited, extras=None):
                    ("q_created", created), ("q_seq", seq)):
         st[k] = st[k].at[rows, free].set(
             jnp.where(ok, val, st[k][rows, free]))
-    st["q_visited"] = st["q_visited"].at[rows, free].set(
-        jnp.where(ok[:, None], visited, st["q_visited"][rows, free]))
+    q_bool = unpack(st["q_visited"], n)
+    st["q_visited"] = pack(q_bool.at[rows, free].set(
+        jnp.where(ok[:, None], unpack(visited, n), q_bool[rows, free])))
     st["seq_counter"] = st["seq_counter"] + jnp.sum(
         ok.astype(jnp.int32), dtype=jnp.int32)
     st["drop_count"] = st["drop_count"] + jnp.sum(mask & ~has_free,
@@ -93,7 +97,7 @@ def ref_compute_pass(st, budget, targets_cum, t_now, cfg):
         jnp.where(completed, False, st["q_active"][rows, head]))
     if trace_record.enabled(cfg):
         st["q_energy"] = st["q_energy"].at[rows, head].add(adv * eJ)
-        hops = jnp.sum(st["q_visited"][rows, head], axis=-1)
+        hops = jnp.sum(unpack(st["q_visited"], n)[rows, head], axis=-1)
         st = trace_record.write_records(
             st, completed, seq=st["q_seq"][rows, head],
             src=st["q_src"][rows, head], dst=rows,
@@ -105,7 +109,8 @@ def ref_compute_pass(st, budget, targets_cum, t_now, cfg):
 
 
 def ref_initiate(st, elig, tgt, t0, profile):
-    rows = jnp.arange(st["F"].shape[0])
+    n = st["F"].shape[0]
+    rows = jnp.arange(n)
     head, _ = head_slot(st)
     cum_h = st["q_cum"][rows, head]
     cum_snap = snap_to_boundary(profile, cum_h)
@@ -132,8 +137,9 @@ def ref_initiate(st, elig, tgt, t0, profile):
     st["tx_cum"] = jnp.where(elig, cum_snap, st["tx_cum"])
     st["tx_created"] = jnp.where(elig, st["q_created"][rows, head],
                                  st["tx_created"])
-    st["tx_visited"] = jnp.where(elig[:, None], st["q_visited"][rows, head],
-                                 st["tx_visited"])
+    st["tx_visited"] = pack(jnp.where(elig[:, None],
+                                      unpack(st["q_visited"], n)[rows, head],
+                                      unpack(st["tx_visited"], n)))
     st["tx_start"] = jnp.where(elig, t0, st["tx_start"])
     st["tx_count"] = st["tx_count"] + jnp.sum(elig, dtype=jnp.int32)
     st["tx_active"] = st["tx_active"] | elig
@@ -173,7 +179,7 @@ def _state(seed: int, fill: str, traced: bool):
     st["q_seq"] = jnp.asarray(rng.permutation(N * Q).reshape(N, Q),
                               jnp.int32)
     st["seq_counter"] = jnp.int32(N * Q)
-    st["q_visited"] = jnp.asarray(rng.random((N, Q, N)) < 0.3)
+    st["q_visited"] = pack(rng.random((N, Q, N)) < 0.3)
     st["xi_label"] = jnp.asarray(rng.integers(0, 3, N), jnp.int32)
     st["xi_layers"] = jnp.asarray(rng.choice(cfg.exit_points, N), jnp.int32)
     st["tx_active"] = jnp.asarray(rng.random(N) < 0.3)
@@ -224,7 +230,7 @@ def test_push_matches_indexed(seed, fill, mode, traced):
     mask = _mask(rng, mode)
     cum = jnp.asarray(rng.uniform(0, 5, N), jnp.float32)
     created = jnp.asarray(rng.uniform(0, 9, N), jnp.float32)
-    visited = jnp.asarray(rng.random((N, N)) < 0.5)
+    visited = pack(rng.random((N, N)) < 0.5)
     extras = None
     if traced:   # an [n] column, a scalar and an int column cast to i32
         extras = {"src": jnp.arange(N), "energy": 0.0,
@@ -286,7 +292,7 @@ def test_vmap_over_runs_matches_indexed(fill):
     rng = np.random.default_rng(7)
     mask = _mask(rng, "random", (3, N))
     cum = jnp.asarray(rng.uniform(0, 5, (3, N)), jnp.float32)
-    visited = jnp.asarray(rng.random((3, N, N)) < 0.5)
+    visited = jax.vmap(pack)(rng.random((3, N, N)) < 0.5)
     budget = jnp.asarray(rng.uniform(0, 12, (3, N)), jnp.float32)
     tgt = jnp.asarray(rng.integers(0, N, (3, N)), jnp.int32)
 
@@ -336,14 +342,6 @@ def test_slot_read_is_the_indexed_gather(dtype):
 # ---------------------------------------------------------------------------
 
 
-def _indexed_operand_shapes(fn, *args):
-    from repro.analysis.jaxpr.jaxpr_util import iter_eqns
-    jaxpr = jax.make_jaxpr(fn)(*args)
-    return [tuple(site.eqn.invars[0].aval.shape)
-            for site in iter_eqns(jaxpr.jaxpr)
-            if site.eqn.primitive.name.startswith(("gather", "scatter"))]
-
-
 @pytest.mark.parametrize("traced", [False, True])
 def test_no_indexed_access_to_slot_fields(traced):
     st, cfg, rng = _state(0, "random", traced)
@@ -355,7 +353,8 @@ def test_no_indexed_access_to_slot_fields(traced):
     tgt = jnp.zeros((N,), jnp.int32)
     progs = {
         "push": (lambda s: queues.push(s, mask, vec, vec,
-                                       jnp.zeros((N, N), bool), extras)),
+                                       jnp.zeros_like(s["tx_visited"]),
+                                       extras)),
         "pop_head": lambda s: queues.pop_head(s, mask),
         "compute_pass": lambda s: _compute_pass(s, vec, vec,
                                                 jnp.float32(1.0), cfg),
@@ -363,7 +362,8 @@ def test_no_indexed_access_to_slot_fields(traced):
                                                 jnp.float32(1.0), profile),
     }
     for name, fn in progs.items():
-        shapes = _indexed_operand_shapes(fn, st)
+        shapes = indexed_operand_shapes(fn, st)
         assert (N, Q) not in shapes, name
-        if name == "push":     # q_visited keeps its indexed row write
-            assert (N, Q, N) in shapes
+        if name == "push":     # the visited words are slot fields too
+            assert st["q_visited"].shape not in shapes
+            assert st["tx_visited"].shape not in shapes

@@ -1,8 +1,7 @@
 """Benchmark entry point — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows (kernel microbench) followed by
-the figure reproductions (Fig. 3-7) and the roofline table from the dry-run
-artifacts.  Env knobs:
+Prints the figure reproductions (Fig. 3-7) and the roofline table from the
+dry-run artifacts.  Env knobs:
   REPRO_FULL_RUNS=1   use the paper's 50 Monte-Carlo runs (default 16)
   REPRO_BENCH_FAST=1  tiny sweep for CI smoke (2 runs)
 
@@ -72,32 +71,14 @@ def watch(path: str, interval: float = 2.0) -> None:
 
 def run_benchmarks() -> None:
     from benchmarks import (fig3_gamma, fig4_workers, fig5_rate, fig6_area,
-                            fig7_earlyexit, microbench, roofline)
+                            fig7_earlyexit, roofline)
     from repro.fleet import worker_env
 
     # fleet sweeps coordinate across ranks through the shared store, but
-    # the microbench/roofline producers don't — running them on every rank
-    # would race the read-modify-write of BENCH_fleet.json and record an
-    # arbitrary rank's wall clock; rank 0 owns them
+    # the ablation/roofline producers don't — running them on every rank
+    # would race the read-modify-write of BENCH_fleet.json; rank 0 owns
+    # them
     rank0 = worker_env().rank == 0
-    if rank0:
-        print("== microbench (name,us_per_call,derived) ==")
-        microbench.run()
-        print("\n== diffusive_phi at swarm scale (ref vs Pallas interpret)"
-              " ==")
-        microbench.run_phi_sweep(ns=(256,) if FAST else (256, 1024, 4096))
-        print("\n== diffusive_phi sparse neighbor-list path (O(N·k)) ==")
-        if FAST:
-            microbench.run_phi_sparse_wallclock(
-                ns=(256,), k=8, dense_ns=(256,), interpret_ns=(128,))
-        else:
-            microbench.run_phi_sparse_wallclock()
-        print("\n== trace-stream overhead (off / tasks / +hops / +state) ==")
-        if FAST:
-            microbench.run_trace_overhead(ns=(256,), sim_time_s=1.0,
-                                          iters=1)
-        else:
-            microbench.run_trace_overhead()
 
     kw = {"runs": 2} if FAST else {}
 

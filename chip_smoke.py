@@ -13,9 +13,9 @@ Phases (one process; weights and swarms are random from ``--seed``):
      at N=30 and N=1024.
   b. Large sparse swarm: ``neighbor_mode="sparse"`` at N=4096, area side
      scaled by sqrt(N/30) to keep Table 2's density, 2 runs, simulated time
-     cut to fit (printed).  The sparse kernel must match
-     ``phi_update_sparse``, and sparse must equal dense bit for bit at a
-     small N where K covers every degree.
+     cut to fit (printed).  ``phi_update_op`` over neighbour lists (the
+     sparse kernel) must match ``phi_update_sparse``, and sparse must
+     equal dense bit for bit at a small N where K covers every degree.
   c. Split serving: qwen3-1.7b at its published widths through
      ``plan_stages`` + ``SplitServeEngine`` (4 executors, batch 4, seq
      128).  One request's logits must match an unsplit forward pass; the
@@ -49,7 +49,8 @@ import numpy as np  # noqa: E402
 from repro.chip import enable_compile_cache  # noqa: E402
 from repro.configs import SwarmConfig, get_config  # noqa: E402
 from repro.core.diffusive import (phi_update, phi_update_op,  # noqa: E402
-                                  phi_update_op_sparse, phi_update_sparse)
+                                  phi_update_sparse)
+from repro.core.neighborhood import Neighborhood  # noqa: E402
 from repro.fleet import executor, run_batch  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -134,9 +135,9 @@ def phase_paper(seed: int, cfg: SwarmConfig, parity_ns=(30, 1024)) -> None:
         raise AssertionError("a: the simulator program holds no φ kernel")
     log("[a] the compiled simulator holds the φ Pallas kernel")
     for i, N in enumerate(parity_ns):
-        args = random_graph(jax.random.fold_in(key, i), N)
-        got = jax.jit(phi_update_op)(*args)
-        want = jax.jit(phi_update)(*args)
+        phi, F, adj, d_tx = random_graph(jax.random.fold_in(key, i), N)
+        got = jax.jit(phi_update_op)(phi, F, Neighborhood(adj), d_tx)
+        want = jax.jit(phi_update)(phi, F, adj, d_tx)
         check_close(got, want, f"a:phi_update_op vs phi_update N={N}",
                     F32_RTOL)
 
@@ -168,12 +169,11 @@ def phase_sparse(seed: int, n: int = 4096, runs: int = 2,
     adj_e, cap_e = link_state_sparse(pos, nbr, valid, cfg)
     F = jax.random.uniform(kf, (n,), jnp.float32, 100, 700)
     d_tx_e = jnp.where(adj_e, 1e4 / cap_e, 1e30)
-    args = (F * 1.5, F, adj_e, nbr, d_tx_e)
-    got = jax.jit(phi_update_op_sparse)(*args)
-    want = jax.jit(phi_update_sparse)(*args)
+    got = jax.jit(phi_update_op)(F * 1.5, F, Neighborhood(adj_e, nbr), d_tx_e)
+    want = jax.jit(phi_update_sparse)(F * 1.5, F, adj_e, nbr, d_tx_e)
     log(f"[b] parity inputs: {int(jnp.sum(adj_e))} edges, max degree "
         f"{int(jnp.max(jnp.sum(adj_e, axis=1)))} of K={cfg.neighbor_k}")
-    check_close(got, want, f"b:phi_update_op_sparse vs phi_update_sparse "
+    check_close(got, want, f"b:phi_update_op (lists) vs phi_update_sparse "
                 f"N={n}", F32_RTOL)
 
     # sparse == dense, bit for bit, where K covers every degree

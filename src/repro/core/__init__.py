@@ -9,11 +9,13 @@ from repro.core.diffusive import (neighbor_mask, phi_bounds_ok, phi_fixpoint,
 from repro.core.early_exit import (CongestionState, congestion_update,
                                    exit_accuracy, exit_boundary_layers,
                                    exit_label, init_congestion)
+from repro.core.neighborhood import Neighborhood
 from repro.core.protocol import (EpochDecision, ProtocolState, decision_epoch,
                                  init_protocol)
 
 __all__ = [
     "phi_update", "phi_fixpoint", "phi_bounds_ok", "neighbor_mask",
+    "Neighborhood",
     "utilization", "transfer_decision", "TransferDecision",
     "CongestionState", "init_congestion", "congestion_update", "exit_label",
     "exit_boundary_layers", "exit_accuracy",

@@ -14,6 +14,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.neighborhood import Neighborhood
+
 BIG = 1e30
 
 
@@ -29,39 +31,18 @@ def utilization(queued_gflops: jax.Array, phi: jax.Array) -> jax.Array:
 
 
 def transfer_decision(queued_gflops: jax.Array, phi: jax.Array,
-                      adj: jax.Array, gamma: float) -> TransferDecision:
+                      nbhd: Neighborhood, gamma: float) -> TransferDecision:
     """Eqs. 11-13 for every node simultaneously.
 
-    queued_gflops [N], phi [N], adj [N, N] bool.  A node with no neighbors
-    never transfers (target = -1).
+    queued_gflops [N], phi [N], ``nbhd`` the one-hop neighbourhoods.  A
+    node with no neighbors never transfers (target = -1).  Neighbour lists
+    are sorted ascending by node id, so utilization ties resolve to the
+    lowest node id in either format.
     """
     U = utilization(queued_gflops, phi)                   # [N]
-    cand = jnp.where(adj, U[None, :], BIG)                # [N, N]
-    # index dtype pinned: argmin yields i64 under x64, and the strategy
-    # switch requires every branch to return the same target dtype (J002)
-    k_star = jnp.argmin(cand, axis=1).astype(jnp.int32)   # [N]
+    cand = jnp.where(nbhd.mask, nbhd.view(U), BIG)        # [N, M]
+    k_star = nbhd.node(jnp.argmin(cand, axis=1))          # [N]
     U_star = jnp.min(cand, axis=1)                        # [N]
-    has_nbr = jnp.any(adj, axis=1)
-    do = has_nbr & ((U - U_star) > gamma)                 # Eq. 13
-    return TransferDecision(U, jnp.where(has_nbr, k_star, -1), do)
-
-
-def transfer_decision_sparse(queued_gflops: jax.Array, phi: jax.Array,
-                             adj_e: jax.Array, nbr: jax.Array,
-                             gamma: float) -> TransferDecision:
-    """Eqs. 11-13 over fixed-width neighbor lists (DESIGN.md §11).
-
-    adj_e [N, K] bool, nbr [N, K] int32.  The argmin runs over the K axis
-    and maps back through the list; because the lists are canonically
-    sorted ascending by node id, utilization ties resolve to the lowest
-    node id — the same winner as the dense argmin.
-    """
-    U = utilization(queued_gflops, phi)                   # [N]
-    rows = jnp.arange(U.shape[0])
-    cand = jnp.where(adj_e, U[nbr], BIG)                  # [N, K]
-    slot = jnp.argmin(cand, axis=1)                       # [N]
-    k_star = nbr[rows, slot]
-    U_star = jnp.min(cand, axis=1)
-    has_nbr = jnp.any(adj_e, axis=1)
+    has_nbr = jnp.any(nbhd.mask, axis=1)
     do = has_nbr & ((U - U_star) > gamma)                 # Eq. 13
     return TransferDecision(U, jnp.where(has_nbr, k_star, -1), do)

@@ -4,9 +4,10 @@
 
 φ is an effective processing rate (GFLOP/s) under even one-hop load
 sharing; the max term is the slowest collaborator.  Fully distributed in the
-protocol sense (one-hop state only); vectorized here as a dense masked
-max-plus row reduction over the [N, N] adjacency (DESIGN.md §3) — the Pallas
-``diffusive_phi`` kernel implements the same contraction with VMEM tiling.
+protocol sense (one-hop state only); vectorized here as a masked max-plus
+row reduction over the neighbourhood, a dense [N, N] adjacency or [N, K]
+neighbour lists (DESIGN.md §2, §11) — the Pallas ``diffusive_phi`` kernels
+implement the same contraction with VMEM tiling.
 
 All functions are pure jnp: they vmap over Monte-Carlo runs and scan over
 decision epochs inside the swarm simulator.
@@ -18,6 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.neighborhood import Neighborhood
 from repro.obs.scopes import phase
 
 NEG = -1e30
@@ -51,28 +53,35 @@ def phi_update(phi: jax.Array, F: jax.Array, adj: jax.Array,
         return jnp.where(deg > 0, phi_new, F)
 
 
-def phi_update_op(phi: jax.Array, F: jax.Array, adj: jax.Array,
+def phi_update_op(phi: jax.Array, F: jax.Array, nbhd: Neighborhood,
                   d_tx: jax.Array) -> jax.Array:
-    """Backend-dispatched ``phi_update`` (the simulator hot path).
+    """Backend-dispatched Eq. 10 (the simulator hot path).
 
-    Routes the [N, N] masked max-plus reduction through
-    ``kernels.ops.diffusive_phi`` — the tiled Pallas kernel on TPU (or in
-    interpret mode under ``REPRO_FORCE_INTERPRET=1``), the jnp reference
-    elsewhere.  Accepts [N] or batched [R, N] operands; the isolated-node
-    fallback (φ_i = F_i exactly) is applied here so results match
-    ``phi_update`` to float32 rounding.
+    Routes the masked max-plus reduction through ``kernels.ops`` —
+    ``diffusive_phi`` over a dense ``[N, N]`` neighbourhood,
+    ``diffusive_phi_sparse`` (a gather-max) over ``[N, K]`` lists: the
+    tiled Pallas kernels on TPU (or in interpret mode under
+    ``REPRO_FORCE_INTERPRET=1``), the jnp references elsewhere.  Accepts
+    [N] or batched [R, N] operands (``d_tx`` shaped like ``nbhd.mask``);
+    the isolated-node fallback (φ_i = F_i exactly) is applied here so
+    results match ``phi_update`` / ``phi_update_sparse`` to float32
+    rounding.
     """
     from repro.kernels import ops  # deferred: keep core import-light
 
     with phase("phi_update"):
         inv_phi = 1.0 / phi
-        dtx_m = jnp.where(adj, d_tx, NEG)
-        if inv_phi.ndim == 1:
-            inv_new = ops.diffusive_phi(inv_phi[None], F[None],
-                                        dtx_m[None])[0]
+        dtx_m = jnp.where(nbhd.mask, d_tx, NEG)
+        if nbhd.ids is None:
+            kernel, ids = ops.diffusive_phi, ()
         else:
-            inv_new = ops.diffusive_phi(inv_phi, F, dtx_m)
-        deg = jnp.sum(adj, axis=-1)
+            kernel, ids = ops.diffusive_phi_sparse, (nbhd.ids,)
+        if inv_phi.ndim == 1:
+            inv_new = kernel(inv_phi[None], F[None], dtx_m[None],
+                             *(x[None] for x in ids))[0]
+        else:
+            inv_new = kernel(inv_phi, F, dtx_m, *ids)
+        deg = jnp.sum(nbhd.mask, axis=-1)
         return jnp.where(deg > 0, 1.0 / inv_new, F)
 
 
@@ -92,29 +101,6 @@ def phi_update_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
         worst = jnp.max(cand, axis=-1)
         deg = jnp.sum(adj_e, axis=-1)
         inv_new = (1.0 / F + worst) / (deg + 1.0)
-        return jnp.where(deg > 0, 1.0 / inv_new, F)
-
-
-def phi_update_op_sparse(phi: jax.Array, F: jax.Array, adj_e: jax.Array,
-                         nbr: jax.Array, d_tx_e: jax.Array) -> jax.Array:
-    """Backend-dispatched ``phi_update_sparse`` (the O(N·k) hot path).
-
-    Routes the gather-max reduction through
-    ``kernels.ops.diffusive_phi_sparse``; accepts [N]/[N,K] or batched
-    [R,N]/[R,N,K] operands.  The isolated-node fallback is applied here,
-    mirroring ``phi_update_op``.
-    """
-    from repro.kernels import ops  # deferred: keep core import-light
-
-    with phase("phi_update"):
-        inv_phi = 1.0 / phi
-        dtx_m = jnp.where(adj_e, d_tx_e, NEG)
-        if inv_phi.ndim == 1:
-            inv_new = ops.diffusive_phi_sparse(inv_phi[None], F[None],
-                                               dtx_m[None], nbr[None])[0]
-        else:
-            inv_new = ops.diffusive_phi_sparse(inv_phi, F, dtx_m, nbr)
-        deg = jnp.sum(adj_e, axis=-1)
         return jnp.where(deg > 0, 1.0 / inv_new, F)
 
 

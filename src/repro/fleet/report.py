@@ -5,8 +5,8 @@
 the paper reports: mean ± 95 % CI per metric, the latency CDF quantiles
 (Fig. 4a-style), Jain fairness and energy per task (J/task).
 ``write_bench_json`` merges a named section into ``BENCH_fleet.json``
-atomically, so independent producers (figure sweeps, the φ microbench, CI
-smoke runs) accumulate into one machine-readable perf-trajectory file.
+atomically, so independent producers (figure sweeps, CI smoke runs)
+accumulate into one machine-readable perf-trajectory file.
 """
 from __future__ import annotations
 

@@ -30,16 +30,24 @@ candidates: the truncated-degree approximation DESIGN.md §11 discusses.
 Lists are canonicalized to ascending node id (invalid slots pushed to the
 end) so downstream argmin/argmax tie-breaks match the dense path's
 lowest-index-wins convention bit-for-bit.
+
+``epoch_links`` is the one reader of ``cfg.neighbor_mode``: it builds an
+epoch's links as a ``core.neighborhood.Neighborhood`` in either format,
+so the decisions downstream never ask which one they hold.
 """
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
+from repro.core.neighborhood import Neighborhood
+from repro.obs.scopes import phase
+from repro.swarm.channel import edge_rate, link_state, link_state_sparse
+from repro.swarm.scenario import get_channel, get_channel_edges
 
 # grid resolution cap: G² cells must stay cheap to searchsorted over
 MAX_GRID = 256
@@ -164,8 +172,33 @@ def neighbor_lists(pos: jax.Array, cfg: SwarmConfig, k: int | None = None
     return jnp.where(valid, nbr, 0).astype(jnp.int32), valid
 
 
-def mask_neighbors(valid: jax.Array, nbr: jax.Array, alive: jax.Array
-                   ) -> jax.Array:
-    """Sparse twin of ``scenario.mask_adjacency``: down nodes have no links
-    in either direction.  valid/nbr [N, K], alive [N] → [N, K]."""
-    return valid & alive[:, None] & alive[nbr]
+def epoch_links(pos: jax.Array, alive: jax.Array, cfg: SwarmConfig, key
+                ) -> Tuple[Neighborhood, jax.Array, Callable]:
+    """One epoch's links in the representation ``cfg.neighbor_mode``
+    selects (read here only): ``(nbhd, cap, rate_to)``.
+
+    * ``nbhd``: the alive-masked one-hop neighbourhoods (Eq. 9), dense
+      ``[N, N]`` or ``[N, K]`` lists from ``neighbor_lists``;
+    * ``cap`` ``[N, M]``: the link capacity in bit/s of each slot
+      (``channel.link_state`` / ``link_state_sparse``);
+    * ``rate_to(dst)``: each node's ``[N]`` rate toward ``dst`` —
+      ``cap[rows, dst]`` dense, ``channel.edge_rate`` for lists, on the
+      same epoch ``key`` so its draws are the ones the decision saw.
+    """
+    if cfg.neighbor_mode == "sparse":
+        edge_fn = get_channel_edges(cfg)
+        with phase("neighbors"):
+            ids, valid = neighbor_lists(pos, cfg)
+            nbhd = Neighborhood(valid, ids).masked(alive)
+        with phase("channel"):
+            adj, cap = link_state_sparse(pos, ids, nbhd.mask, cfg, key=key,
+                                         pathloss_fn=edge_fn)
+        return (nbhd._replace(mask=adj), cap,
+                lambda dst: edge_rate(pos, dst, cfg, key=key,
+                                      pathloss_fn=edge_fn))
+    with phase("channel"):
+        adj, cap = link_state(pos, cfg, key=key, pathloss_fn=get_channel(cfg))
+        nbhd = Neighborhood(adj).masked(alive)
+    # i32 rows: default arange is i64 under x64 (swarmlint J002)
+    rows = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    return nbhd, cap, lambda dst: cap[rows, dst]

@@ -21,6 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.neighborhood import Neighborhood
 from repro.obs.scopes import phase
 from repro.swarm.tasks import ProfileMix, pick
 
@@ -107,6 +108,16 @@ def has_node(words, ids) -> jax.Array:
     rows = jnp.arange(ids.shape[0], dtype=jnp.int32)[:, None]
     w = words[ids // WORD, rows]
     return ((w >> (ids % WORD).astype(jnp.uint32)) & jnp.uint32(1)) == 1
+
+
+def visited_neighbors(words, nbhd: Neighborhood) -> jax.Array:
+    """``bool`` shaped like ``nbhd.mask``: whether set ``i`` of ``words``
+    ``[W, n]`` holds the node in each neighbour slot — the sets unpacked
+    over a dense neighbourhood, a bit test at the ids of neighbour lists
+    (an ``[n, K]`` gather of words, never an ``[n, n]`` row)."""
+    if nbhd.ids is None:
+        return unpack_visited(words, nbhd.mask.shape[1])
+    return has_node(words, nbhd.ids)
 
 
 def hop_count(words) -> jax.Array:

@@ -141,11 +141,6 @@ def _fault_markov_step(alive, key, cfg: SwarmConfig):
     return jnp.where(alive, u >= p_fail, u < p_recover)
 
 
-def mask_adjacency(adj: jax.Array, alive: jax.Array) -> jax.Array:
-    """Down nodes have no links in either direction."""
-    return adj & alive[:, None] & alive[None, :]
-
-
 # ---------------------------------------------------------------------------
 # workload: Markov-modulated (bursty) arrivals — part of the scenario
 # ---------------------------------------------------------------------------

@@ -11,8 +11,10 @@ This module is only the scan skeleton + strategy dispatch; the parts live in
   * ``swarm/scenario.py`` — mobility/channel/fault registries + arrivals,
   * ``swarm/queues.py``   — struct-of-arrays task-queue ops,
   * ``swarm/transfer.py`` — transfer initiate/progress/deliver,
-and the epoch φ update dispatches through ``kernels/ops.diffusive_phi``
-(Pallas on TPU, jnp reference elsewhere) via ``core.diffusive.phi_update_op``.
+  * ``swarm/neighbors.py`` — the epoch's links in the configured format,
+and Algorithm 1 itself (φ, the Distributed decision, the early exit) is
+``core.protocol.decision_epoch``, whose φ update dispatches through
+``kernels/ops`` (Pallas on TPU, jnp reference elsewhere).
 
 Strategies (paper §5): 0 LocalOnly · 1 Random · 2 RandomAcyclic · 3 Greedy ·
 4 Distributed (ours, diffusive φ).  The strategy id is a *traced* scalar so
@@ -27,22 +29,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
-from repro.core.decision import transfer_decision, transfer_decision_sparse
-from repro.core.diffusive import phi_update_op, phi_update_op_sparse
-from repro.core.early_exit import (congestion_update, exit_accuracy,
-                                   exit_boundary_layers, exit_label)
-from repro.core.early_exit import CongestionState
+from repro.core.early_exit import CongestionState, exit_accuracy
+from repro.core.neighborhood import Neighborhood
+from repro.core.protocol import ProtocolState, decision_epoch
 from repro.obs.scopes import phase
 from repro.swarm import transfer as transfer_mod
-from repro.swarm.channel import edge_rate, link_state, link_state_sparse
-from repro.swarm.neighbors import mask_neighbors, neighbor_lists
-from repro.swarm.queues import (has_node, head_slot, head_visited,
-                                hop_count, push, queued_gflops, slot_add,
-                                slot_mask, slot_read, slot_write,
-                                unpack_visited, visited_words)
-from repro.swarm.scenario import (burst_arrivals, get_channel,
-                                  get_channel_edges, get_fault,
-                                  get_mobility, mask_adjacency)
+from repro.swarm.neighbors import epoch_links
+from repro.swarm.queues import (head_slot, head_visited, hop_count, push,
+                                queued_gflops, slot_add, slot_mask,
+                                slot_read, slot_write, visited_neighbors,
+                                visited_words)
+from repro.swarm.scenario import burst_arrivals, get_fault, get_mobility
 from repro.swarm.tasks import (PROFILE_KEY, ProfileMix, draw_profiles,
                                is_mix, make_profile, pick)
 from repro.trace import record as trace_record
@@ -189,7 +186,7 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig,
     return st, budget - adv
 
 
-def _tick(st, key, cfg: SwarmConfig, profile, cap, alive, t_now):
+def _tick(st, key, cfg: SwarmConfig, profile, rate, alive, t_now):
     n = st["F"].shape[0]
     tick = cfg.tick_s
     mix = profile if isinstance(profile, ProfileMix) else None
@@ -233,7 +230,7 @@ def _tick(st, key, cfg: SwarmConfig, profile, cap, alive, t_now):
 
     # (c) transfer progress + delivery
     with phase("transfers"):
-        return transfer_mod.progress(st, cap, alive, cfg, t_now)
+        return transfer_mod.progress(st, rate, alive, cfg, t_now)
 
 
 # ---------------------------------------------------------------------------
@@ -241,23 +238,38 @@ def _tick(st, key, cfg: SwarmConfig, profile, cap, alive, t_now):
 # ---------------------------------------------------------------------------
 
 
-def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
-    """Returns (do_transfer [N] bool, target [N] i32, phi')."""
+def _strategy_decision(st, strategy, nbhd: Neighborhood, d_tx, T, key,
+                       cfg: SwarmConfig):
+    """Returns (do_transfer [N] bool, target [N] i32, EpochDecision).
+
+    Every arm reduces over the neighbourhood's slot axis and maps the
+    chosen column back to a node id, so one body serves both formats.
+    Offload coins and Greedy/Distributed targets are the same in either
+    (id-sorted lists keep the lowest-index tie-break); Random and
+    RandomAcyclic draw their target gumbels over ``nbhd.mask``'s shape,
+    per node pair dense and per slot for lists — different streams, each
+    uniform over the same neighbour sets.
+    """
     n = st["F"].shape[0]
     k1, k2, k3 = jax.random.split(key, 3)
     head, has = head_slot(st)
-    has_nbr = jnp.any(adj, axis=1)
+    has_nbr = jnp.any(nbhd.mask, axis=1)
 
-    # ---- Distributed (ours): Eqs. 10-13, kernel-dispatched ----------------
-    phi = phi_update_op(st["phi"], st["F"], adj, d_tx)
-    dec = transfer_decision(T, phi, adj, cfg.gamma)
-    dist = (dec.transfer, dec.target)
+    # ---- Distributed (ours): Alg. 1 lines 2-11, kernel-dispatched --------
+    ep = decision_epoch(
+        ProtocolState(st["phi"], CongestionState(st["cong_prev"],
+                                                 st["cong_D"])),
+        F=st["F"], nbhd=nbhd, d_tx=d_tx, queued_gflops=T, gamma=cfg.gamma,
+        dt=cfg.decision_period_s, alpha=cfg.ema_alpha,
+        tau_med=cfg.exit_thresholds[0], tau_high=cfg.exit_thresholds[1],
+        exit_points=cfg.exit_points,
+        finalize_layers=cfg.exit_finalize_layers,
+        early_exit_enabled=cfg.early_exit_enabled)
+    dist = (ep.decision.transfer, ep.decision.target)
 
     # ---- Greedy: least instantaneous load, w.p. p_greedy -----------------
-    cand = jnp.where(adj, T[None, :], BIG)
-    # target dtypes pinned to i32: argmin/argmax are i64 under x64 and the
-    # strategy switch needs branch-identical avals (swarmlint J002)
-    g_tgt = jnp.argmin(cand, axis=1).astype(jnp.int32)
+    cand = jnp.where(nbhd.mask, nbhd.view(T), BIG)
+    g_tgt = nbhd.node(jnp.argmin(cand, axis=1))
     g_less = jnp.min(cand, axis=1) < T
     g_do = (jax.random.bernoulli(k1, cfg.greedy_offload_p, (n,))
             & has_nbr & g_less)
@@ -267,20 +279,21 @@ def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
     # NB: the offload coin must not share k2 with the gumbel target draw —
     # threefry counters would make coin u_j bit-identical to a target score
     # for j, correlating "who offloads" with "who gets picked"
-    gum = jax.random.gumbel(k2, (n, n))
-    r_tgt = jnp.argmax(jnp.where(adj, gum, -BIG), axis=1).astype(jnp.int32)
+    gum = jax.random.gumbel(k2, nbhd.mask.shape)
+    r_tgt = nbhd.node(jnp.argmax(jnp.where(nbhd.mask, gum, -BIG), axis=1))
     r_do = jax.random.bernoulli(jax.random.fold_in(k2, 1),
                                 cfg.random_offload_p, (n,)) & has_nbr
     random_ = (r_do, r_tgt)
 
     # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
     with phase("visited"):
-        visited_head = unpack_visited(head_visited(
-            st["q_visited"], slot_mask(head, st["q_active"].shape[1])), n)
-    amask = adj & ~visited_head
+        visited = visited_neighbors(head_visited(
+            st["q_visited"], slot_mask(head, st["q_active"].shape[1])), nbhd)
+    amask = nbhd.mask & ~visited
     a_has = jnp.any(amask, axis=1)
-    a_tgt = jnp.argmax(jnp.where(amask, jax.random.gumbel(k3, (n, n)), -BIG),
-                       axis=1).astype(jnp.int32)
+    a_tgt = nbhd.node(jnp.argmax(
+        jnp.where(amask, jax.random.gumbel(k3, nbhd.mask.shape), -BIG),
+        axis=1))
     a_do = jax.random.bernoulli(jax.random.fold_in(k3, 1),
                                 cfg.random_acyclic_p, (n,)) & a_has
     acyc = (a_do, a_tgt)
@@ -293,71 +306,7 @@ def _strategy_decision(st, strategy, adj, d_tx, T, key, cfg: SwarmConfig):
     tgt = jax.lax.switch(strategy, [
         lambda: local[1], lambda: random_[1], lambda: acyc[1],
         lambda: greedy[1], lambda: dist[1]])
-    return do, tgt, phi
-
-
-def _strategy_decision_sparse(st, strategy, adj_e, nbr, d_tx_e, T, key,
-                              cfg: SwarmConfig):
-    """Neighbor-list twin of ``_strategy_decision``: every per-strategy
-    reduction runs over the K axis and maps back through ``nbr``.
-
-    Offload coins reuse the dense keys/shapes, so *whether* a node
-    offloads is bit-identical to dense; Greedy/Distributed targets match
-    too (id-sorted lists preserve the lowest-index tie-break).  Only the
-    Random/RandomAcyclic *target* draws differ — their gumbel field is
-    per-slot [N, K] instead of per-node-pair [N, N], an intentionally
-    different stream (still uniform over the same neighbor sets).
-    """
-    n = st["F"].shape[0]
-    k1, k2, k3 = jax.random.split(key, 3)
-    head, has = head_slot(st)
-    rows = jnp.arange(n)
-    has_nbr = jnp.any(adj_e, axis=1)
-    K = nbr.shape[1]
-
-    # ---- Distributed (ours): Eqs. 10-13, kernel-dispatched ----------------
-    phi = phi_update_op_sparse(st["phi"], st["F"], adj_e, nbr, d_tx_e)
-    dec = transfer_decision_sparse(T, phi, adj_e, nbr, cfg.gamma)
-    dist = (dec.transfer, dec.target)
-
-    # ---- Greedy: least instantaneous load, w.p. p_greedy -----------------
-    cand = jnp.where(adj_e, T[nbr], BIG)
-    g_tgt = nbr[rows, jnp.argmin(cand, axis=1)]
-    g_less = jnp.min(cand, axis=1) < T
-    g_do = (jax.random.bernoulli(k1, cfg.greedy_offload_p, (n,))
-            & has_nbr & g_less)
-    greedy = (g_do, g_tgt)
-
-    # ---- Random: uniform neighbor, w.p. 0.2 ------------------------------
-    gum = jax.random.gumbel(k2, (n, K))
-    r_tgt = nbr[rows, jnp.argmax(jnp.where(adj_e, gum, -BIG), axis=1)]
-    r_do = jax.random.bernoulli(jax.random.fold_in(k2, 1),
-                                cfg.random_offload_p, (n,)) & has_nbr
-    random_ = (r_do, r_tgt)
-
-    # ---- RandomAcyclic: uniform unvisited neighbor, w.p. 0.1 -------------
-    # a bit test of the head task's packed set at the K neighbour ids: an
-    # [N, K] gather of words, never an [N, N] row
-    with phase("visited"):
-        visited_nbr = has_node(head_visited(
-            st["q_visited"], slot_mask(head, st["q_active"].shape[1])), nbr)
-    amask = adj_e & ~visited_nbr
-    a_has = jnp.any(amask, axis=1)
-    a_tgt = nbr[rows, jnp.argmax(
-        jnp.where(amask, jax.random.gumbel(k3, (n, K)), -BIG), axis=1)]
-    a_do = jax.random.bernoulli(jax.random.fold_in(k3, 1),
-                                cfg.random_acyclic_p, (n,)) & a_has
-    acyc = (a_do, a_tgt)
-
-    local = (jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32))
-
-    do = jax.lax.switch(strategy, [
-        lambda: local[0], lambda: random_[0], lambda: acyc[0],
-        lambda: greedy[0], lambda: dist[0]])
-    tgt = jax.lax.switch(strategy, [
-        lambda: local[1], lambda: random_[1], lambda: acyc[1],
-        lambda: greedy[1], lambda: dist[1]])
-    return do, tgt, phi
+    return do, tgt, ep
 
 
 def _transfer_bits_per_gflop(st, profile):
@@ -386,10 +335,9 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
         k_ch = jax.random.fold_in(key, 13)
         k_fault = jax.random.fold_in(key, 17)
 
-    # 1. refresh the scenario at epoch start; 2. strategy decision (Alg. 1
-    #    lines 2-5).  neighbor_mode is static config, so the branch picks
-    #    the compiled representation: dense [N, N] (the historical
-    #    bit-exact path) or [N, K] neighbor lists (O(N·k), DESIGN.md §11)
+    # 1. refresh the scenario at epoch start; 2. the epoch's links in the
+    #    configured representation (swarm/neighbors.py); 3. strategy
+    #    decision and early exit (Alg. 1 lines 2-11, Eqs. 10-16)
     st = dict(st)
     with phase("faults"):
         st["alive"] = get_fault(cfg).step(st["alive"], k_fault, cfg)
@@ -398,43 +346,15 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
     with phase("decision"):
         T = queued_gflops(st, profile)
         bpg = _transfer_bits_per_gflop(st, profile)
-    sparse = cfg.neighbor_mode == "sparse"
-    if sparse:
-        edge_fn = get_channel_edges(cfg)
-        with phase("neighbors"):
-            nbr, valid = neighbor_lists(pos, cfg)
-            valid = mask_neighbors(valid, nbr, st["alive"])
-        with phase("channel"):
-            adj_e, cap_e = link_state_sparse(pos, nbr, valid, cfg, key=k_ch,
-                                             pathloss_fn=edge_fn)
-            d_tx_e = jnp.where(adj_e, bpg / cap_e, BIG)
-        with phase("decision"):
-            do, tgt, phi = _strategy_decision_sparse(
-                st, strategy, adj_e, nbr, d_tx_e, T, kd, cfg)
-    else:
-        with phase("channel"):
-            adj, cap = link_state(pos, cfg, key=k_ch,
-                                  pathloss_fn=get_channel(cfg))
-            adj = mask_adjacency(adj, st["alive"])
-            d_tx = jnp.where(adj, bpg / cap, BIG)
-        with phase("decision"):
-            do, tgt, phi = _strategy_decision(st, strategy, adj, d_tx, T,
-                                              kd, cfg)
-    st["phi"] = phi
-
-    # 3. congestion-aware early exit (Alg. 1 lines 10-11, Eqs. 14-16)
-    with phase("early_exit"):
-        cong = congestion_update(
-            CongestionState(st["cong_prev"], st["cong_D"]), T,
-            cfg.decision_period_s, cfg.ema_alpha)
-        st["cong_prev"], st["cong_D"] = cong.prev_T, cong.D
-        if cfg.early_exit_enabled:
-            lbl = exit_label(cong.D, *cfg.exit_thresholds)
-        else:
-            lbl = jnp.zeros((st["F"].shape[0],), jnp.int32)
-        st["xi_label"] = lbl
-        st["xi_layers"] = exit_boundary_layers(lbl, cfg.exit_points,
-                                               cfg.exit_finalize_layers)
+    nbhd, cap, rate_to = epoch_links(pos, st["alive"], cfg, k_ch)
+    with phase("channel"):
+        d_tx = jnp.where(nbhd.mask, bpg / cap, BIG)
+    with phase("decision"):
+        do, tgt, ep = _strategy_decision(st, strategy, nbhd, d_tx, T, kd,
+                                         cfg)
+    st["phi"] = ep.state.phi
+    st["cong_prev"], st["cong_D"] = ep.state.congestion
+    st["xi_label"], st["xi_layers"] = ep.exit_lbl, ep.exit_layers
 
     # 4. initiate transfers: pop head, snap to boundary (§3.1 discard)
     with phase("initiate"):
@@ -442,23 +362,17 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
         elig = do & has & ~st["tx_active"] & (tgt >= 0)
         st = transfer_mod.initiate(st, elig, tgt, t0, profile)
 
-    # 5. fine ticks.  tx_dst is frozen between decisions, so the sparse
-    #    path resolves each node's outgoing link rate [N] once per epoch
-    #    instead of carrying the [N, N] capacity matrix into the scan —
-    #    same epoch key, so stochastic draws match the decision stage's
-    if sparse:
-        with phase("channel"):
-            link = edge_rate(pos, st["tx_dst"], cfg, key=k_ch,
-                             pathloss_fn=edge_fn)
-    else:
-        link = cap
+    # 5. fine ticks.  tx_dst is frozen between decisions, so each node's
+    #    outgoing link rate [N] is resolved once per epoch
+    with phase("channel"):
+        rate = rate_to(st["tx_dst"])
     n_ticks = int(round(cfg.decision_period_s / cfg.tick_s))
 
     def tick_body(st, i):
         t_now = t0 + (i.astype(jnp.float32) + 1.0) * cfg.tick_s
         with phase("keys"):
             k = jax.random.fold_in(kt, i)
-        st = _tick(st, k, cfg, profile, link, st["alive"], t_now)
+        st = _tick(st, k, cfg, profile, rate, st["alive"], t_now)
         return st, None
 
     st, _ = jax.lax.scan(tick_body, st, jnp.arange(n_ticks))

@@ -82,23 +82,21 @@ def initiate(st, elig, tgt, t0, profile):
     return pop_head(st, elig)
 
 
-def progress(st, cap, alive, cfg: SwarmConfig, t_now):
+def progress(st, rate, alive, cfg: SwarmConfig, t_now):
     """One tick of transfer progress + delivery.
 
-    ``cap`` is the epoch-frozen capacity: the [N,N] matrix on the dense
-    path (indexed per node at its transfer destination), or an [N] rate
-    vector on the sparse neighbor-list path, where the simulator already
-    resolved each node's (i, tx_dst_i) link via ``channel.edge_rate`` —
-    valid because tx_dst only changes at epoch decisions, never mid-tick.
-    ``alive`` is the epoch fault mask — a transfer whose endpoint is down
-    stalls (bits conserved) and resumes when the node recovers.
+    ``rate`` ``[N]`` is each node's epoch-frozen link rate toward its
+    transfer destination in bit/s (``neighbors.epoch_links``' ``rate_to``)
+    — resolved once per epoch, because tx_dst only changes at epoch
+    decisions, never mid-tick.  ``alive`` is the epoch fault mask — a
+    transfer whose endpoint is down stalls (bits conserved) and resumes
+    when the node recovers.
     """
     n = st["F"].shape[0]
     # i32 pin: the origin ranks scatter into i32 contention fields, and
     # default arange/full are i64 under x64 (swarmlint J002)
     rows = jnp.arange(n, dtype=jnp.int32)
     tick = cfg.tick_s
-    rate = cap if cap.ndim == 1 else cap[rows, st["tx_dst"]]  # bit/s
     live = alive & alive[st["tx_dst"]]
     active = st["tx_active"] & live
     # a fully-arrived payload is off the air: no further bit decrement or

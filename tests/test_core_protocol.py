@@ -5,10 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings
 
-from repro.core import (CongestionState, congestion_update, decision_epoch,
-                        exit_accuracy, exit_boundary_layers, exit_label,
-                        init_protocol, phi_bounds_ok, phi_fixpoint,
-                        phi_update, transfer_decision)
+from repro.core import (CongestionState, Neighborhood, congestion_update,
+                        decision_epoch, exit_accuracy, exit_boundary_layers,
+                        exit_label, init_protocol, phi_bounds_ok,
+                        phi_fixpoint, phi_update, transfer_decision)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -101,18 +101,18 @@ def test_transfer_picks_least_utilized_neighbor_and_respects_gamma():
     phi = jnp.asarray([100.0, 100.0, 100.0])
     T = jnp.asarray([50.0, 10.0, 30.0])      # U = [.5, .1, .3]
     adj = jnp.asarray(~np.eye(3, dtype=bool))
-    dec = transfer_decision(T, phi, adj, gamma=0.1)
+    dec = transfer_decision(T, phi, Neighborhood(adj), gamma=0.1)
     assert int(dec.target[0]) == 1           # least utilized neighbor
     assert bool(dec.transfer[0])             # 0.5 - 0.1 > γ
     assert not bool(dec.transfer[1])         # already the least utilized
     # γ hysteresis: huge γ → nobody transfers
-    dec2 = transfer_decision(T, phi, adj, gamma=10.0)
+    dec2 = transfer_decision(T, phi, Neighborhood(adj), gamma=10.0)
     assert not bool(jnp.any(dec2.transfer))
 
 
 def test_no_neighbors_means_no_transfer():
     dec = transfer_decision(jnp.asarray([99.0]), jnp.asarray([1.0]),
-                            jnp.zeros((1, 1), bool), gamma=0.0)
+                            Neighborhood(jnp.zeros((1, 1), bool)), gamma=0.0)
     assert not bool(dec.transfer[0]) and int(dec.target[0]) == -1
 
 
@@ -172,7 +172,7 @@ def test_decision_epoch_runs_and_is_consistent():
     adj, d_tx = ring_topology(n)
     state = init_protocol(F)
     out = decision_epoch(
-        state, F=F, adj=adj, d_tx=d_tx,
+        state, F=F, nbhd=Neighborhood(adj), d_tx=d_tx,
         queued_gflops=jnp.asarray(rng.uniform(0, 100, n), jnp.float32),
         gamma=0.02, dt=0.2, alpha=0.3, tau_med=1.5, tau_high=2.5,
         exit_points=(15, 30, 60), finalize_layers=3)

@@ -183,9 +183,9 @@ def _contention_state(cfg, bits, rate):
         st["hop_seq"] = jnp.asarray([0, 1, 0], jnp.int32)
         st["hop_bits"] = st["tx_bits"]
         st["hop_counter"] = jnp.int32(2)
-    cap = jnp.full((3, 3), rate, jnp.float32)
+    rate = jnp.full((3,), rate, jnp.float32)
     alive = jnp.ones((3,), bool)
-    return st, cap, alive
+    return st, rate, alive
 
 
 def test_contended_delivery_energy_pins_to_single_transfer_value():
@@ -197,12 +197,12 @@ def test_contended_delivery_energy_pins_to_single_transfer_value():
     tick = cfg.tick_s
     tx_w = 10.0 ** (cfg.tx_power_dbm / 10.0) * 1e-3
     # both payloads arrive within one tick
-    st, cap, alive = _contention_state(cfg, bits=100.0, rate=100.0 / tick)
-    st = transfer_mod.progress(st, cap, alive, cfg, tick)        # tick 1
+    st, rate, alive = _contention_state(cfg, bits=100.0, rate=100.0 / tick)
+    st = transfer_mod.progress(st, rate, alive, cfg, tick)        # tick 1
     assert bool(st["tx_active"][1]) and not bool(st["tx_active"][0])
     assert float(jnp.sum(st["e_tx"])) == pytest.approx(2 * tx_w * tick)
     bits_frozen = float(st["tx_bits"][1])
-    st = transfer_mod.progress(st, cap, alive, cfg, 2 * tick)    # tick 2
+    st = transfer_mod.progress(st, rate, alive, cfg, 2 * tick)    # tick 2
     assert not bool(st["tx_active"][1])                          # delivered
     # no further accrual for the waiting tick, bits frozen at arrival
     assert float(jnp.sum(st["e_tx"])) == pytest.approx(2 * tx_w * tick)
@@ -225,12 +225,12 @@ def test_avg_transfer_time_uses_delivered_denominator():
     cfg = dataclasses.replace(SwarmConfig(), num_workers=3)
     profile = make_profile(cfg)
     tick = cfg.tick_s
-    st, cap, alive = _contention_state(cfg, bits=100.0, rate=100.0 / tick)
+    st, rate, alive = _contention_state(cfg, bits=100.0, rate=100.0 / tick)
     # sender 1 now targets a different receiver but with a huge payload:
     # it is still in flight when the sim ends
     st["tx_dst"] = jnp.asarray([2, 0, 0], jnp.int32)
     st["tx_bits"] = jnp.asarray([100.0, 1e12, 0.0], jnp.float32)
-    st = transfer_mod.progress(st, cap, alive, cfg, tick)
+    st = transfer_mod.progress(st, rate, alive, cfg, tick)
     out = {k: float(v) for k, v in sim.summarize(st, cfg, profile).items()}
     assert out["transfers"] == 2.0
     assert out["transfers_delivered"] == 1.0
@@ -248,10 +248,10 @@ def test_hop_energy_join_reproduces_e_tx():
                               trace_hop_capacity=64)
     tick = cfg.tick_s
     tx_w = 10.0 ** (cfg.tx_power_dbm / 10.0) * 1e-3
-    st, cap, alive = _contention_state(cfg, bits=100.0,
+    st, rate, alive = _contention_state(cfg, bits=100.0,
                                        rate=100.0 / (2 * tick))
     for i in range(1, 8):
-        st = transfer_mod.progress(st, cap, alive, cfg, i * tick)
+        st = transfer_mod.progress(st, rate, alive, cfg, i * tick)
     assert float(st["tx_delivered"]) == 2.0
     hdec = decode_hops(np.asarray(st["trace_hops"]))
     air = hop_airtime_s(hdec, tick)

@@ -10,10 +10,10 @@ import pytest
 
 from repro.configs.base import SwarmConfig
 from repro.core.diffusive import phi_update, phi_update_op
+from repro.core.neighborhood import Neighborhood
 from repro.swarm import (CHANNEL_MODELS, DISTRIBUTED, FAULT_MODELS,
                          MOBILITY_MODELS, get_channel, get_fault,
-                         get_mobility, make_profile, mask_adjacency,
-                         run_many)
+                         get_mobility, make_profile, run_many)
 from repro.swarm.channel import link_state
 
 KEY = jax.random.PRNGKey(0)
@@ -252,7 +252,7 @@ def test_fault_markov_adjacency_invariants():
     seen_down = False
     for i in range(50):
         alive = model.step(alive, jax.random.fold_in(KEY, i), cfg)
-        adj = mask_adjacency(full, alive)
+        adj = Neighborhood(full).masked(alive).mask
         a = np.asarray(adj)
         al = np.asarray(alive)
         # no edge may touch a down node, in either direction
@@ -326,7 +326,7 @@ def test_phi_update_op_matches_phi_update_interpret(monkeypatch):
     d_tx = jnp.where(adj, jax.random.uniform(k4, (n, n), jnp.float32,
                                              1e-4, 1e-2), 1e30)
     want = phi_update(phi, F, adj, d_tx)
-    got = phi_update_op(phi, F, adj, d_tx)
+    got = phi_update_op(phi, F, Neighborhood(adj), d_tx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     jax.clear_caches()
 

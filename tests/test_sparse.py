@@ -26,7 +26,8 @@ import pytest
 
 from repro.configs.base import SwarmConfig
 from repro.core.diffusive import (NEG, phi_update, phi_update_op,
-                                  phi_update_op_sparse, phi_update_sparse)
+                                  phi_update_sparse)
+from repro.core.neighborhood import Neighborhood
 from repro.fleet import run_batch
 from repro.kernels import ref
 from repro.kernels.diffusive_phi import \
@@ -189,8 +190,8 @@ def test_phi_update_sparse_bitwise_matches_dense():
     dense = phi_update(F, F, adj, dtx)
     sparse = phi_update_sparse(F, F, adj_e, nbr, dtx_e)
     np.testing.assert_array_equal(np.asarray(sparse), np.asarray(dense))
-    dense_op = phi_update_op(F, F, adj, dtx)
-    sparse_op = phi_update_op_sparse(F, F, adj_e, nbr, dtx_e)
+    dense_op = phi_update_op(F, F, Neighborhood(adj), dtx)
+    sparse_op = phi_update_op(F, F, Neighborhood(adj_e, nbr), dtx_e)
     np.testing.assert_array_equal(np.asarray(sparse_op),
                                   np.asarray(dense_op))
 

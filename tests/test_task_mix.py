@@ -147,8 +147,8 @@ def test_offloaded_task_keeps_its_profile():
     assert float(st["tx_cum"][0]) == float(mix.cum_gflops[1, 1])
     assert float(st["tx_cum"][1]) == float(mix.cum_gflops[0, 1])
     st["tx_bits"] = jnp.where(elig, 0.0, st["tx_bits"])
-    cap = jnp.full((4, 4), 1e9, jnp.float32)
-    st = transfer.progress(st, cap, jnp.ones((4,), bool), cfg, 0.01)
+    rate = jnp.full((4,), 1e9, jnp.float32)
+    st = transfer.progress(st, rate, jnp.ones((4,), bool), cfg, 0.01)
     assert st["q_active"][3, :3].tolist() == [True, True, False]
     assert st["q_profile"][3, :2].tolist() == [0, 1]
     assert float(st["q_cum"][3, 1]) == float(mix.cum_gflops[1, 1])

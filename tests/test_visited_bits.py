@@ -21,13 +21,14 @@ import numpy as np
 import pytest
 
 from repro.configs.base import SwarmConfig
+from repro.core.neighborhood import Neighborhood
 from repro.fleet import run_batch
 from repro.swarm import queues, transfer
 from repro.swarm.queues import (has_node, head_slot, head_visited,
                                 hop_count, node_bits, slot_mask,
                                 unpack_visited, visited_words)
 from repro.swarm.simulator import (BIG, RANDOM_ACYCLIC, _strategy_decision,
-                                   _strategy_decision_sparse, init_state)
+                                   init_state)
 from repro.swarm.tasks import make_profile
 from repro.trace import record as trace_record
 from repro.trace import schema
@@ -256,8 +257,8 @@ def test_progress_marks_origin(n, fill, traced):
     tx_dst = rng.integers(0, n, n).astype(np.int32)
     st.update(tx_active=jnp.asarray(tx_active), tx_bits=jnp.asarray(tx_bits),
               tx_dst=jnp.asarray(tx_dst))
-    cap = jnp.zeros((n, n), jnp.float32)       # nothing in flight moves
-    got = transfer.progress(st, cap, jnp.ones((n,), bool), cfg,
+    rate = jnp.zeros((n,), jnp.float32)        # nothing in flight moves
+    got = transfer.progress(st, rate, jnp.ones((n,), bool), cfg,
                             jnp.float32(1.0))
     won = ref_deliveries(tx_active, tx_bits, tx_dst)
     assert won
@@ -303,19 +304,17 @@ def test_acyclic_decision_matches_bool(n, path, sets):
         adj = rng.random((n, n)) < 0.4
         np.fill_diagonal(adj, False)
         ids = np.broadcast_to(np.arange(n), (n, n))
-        do, tgt, _ = _strategy_decision(
-            st, jnp.int32(RANDOM_ACYCLIC), jnp.asarray(adj),
-            jnp.ones((n, n), jnp.float32), T, key, cfg)
+        nbhd = Neighborhood(jnp.asarray(adj))
     else:
         K = 8
         ids = np.sort(np.stack([rng.choice(np.delete(np.arange(n), i), K,
                                            replace=False)
                                 for i in range(n)]), axis=1)
         adj = rng.random((n, K)) < 0.6
-        do, tgt, _ = _strategy_decision_sparse(
-            st, jnp.int32(RANDOM_ACYCLIC), jnp.asarray(adj),
-            jnp.asarray(ids, jnp.int32), jnp.ones((n, K), jnp.float32), T,
-            key, cfg)
+        nbhd = Neighborhood(jnp.asarray(adj), jnp.asarray(ids, jnp.int32))
+    do, tgt, _ = _strategy_decision(
+        st, jnp.int32(RANDOM_ACYCLIC), nbhd,
+        jnp.ones(adj.shape, jnp.float32), T, key, cfg)
     want_do, want_tgt = ref_acyclic(key, adj, row_bool, ids, 1.0)
     np.testing.assert_array_equal(np.asarray(do), want_do)
     np.testing.assert_array_equal(np.asarray(tgt)[want_do],
@@ -343,7 +342,7 @@ def test_vmap_over_runs_matches_bool():
     vis_bool = rng.random((3, n, n)) < 0.5
     vis = jax.vmap(pack)(vis_bool)
     vec = jnp.zeros((n,), jnp.float32)
-    cap = jnp.zeros((n, n), jnp.float32)
+    rate = jnp.zeros((n,), jnp.float32)
 
     def one(st, m, v):
         st = queues.push(st, m, vec, vec, v)
@@ -351,7 +350,7 @@ def test_vmap_over_runs_matches_bool():
         st = transfer.initiate(st, m & jnp.any(st["q_active"], axis=1),
                                jnp.zeros((n,), jnp.int32), jnp.float32(0.0),
                                profile)
-        return mid, transfer.progress(st, cap, jnp.ones((n,), bool), cfg,
+        return mid, transfer.progress(st, rate, jnp.ones((n,), bool), cfg,
                                       jnp.float32(1.0))
 
     mid, got = jax.jit(jax.vmap(one))(batch, jnp.asarray(mask), vis)

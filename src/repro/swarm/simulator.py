@@ -186,7 +186,8 @@ def _compute_pass(st, budget, targets_cum, t_now, cfg: SwarmConfig,
     return st, budget - adv
 
 
-def _tick(st, key, cfg: SwarmConfig, profile, rate, alive, t_now):
+def _tick(st, key, cfg: SwarmConfig, profile, rate, alive, t_now,
+          dense: bool):
     n = st["F"].shape[0]
     tick = cfg.tick_s
     mix = profile if isinstance(profile, ProfileMix) else None
@@ -230,7 +231,8 @@ def _tick(st, key, cfg: SwarmConfig, profile, rate, alive, t_now):
 
     # (c) transfer progress + delivery
     with phase("transfers"):
-        return transfer_mod.progress(st, rate, alive, cfg, t_now)
+        return transfer_mod.progress(st, rate, alive, cfg, t_now,
+                                     dense=dense)
 
 
 # ---------------------------------------------------------------------------
@@ -363,7 +365,9 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
         st = transfer_mod.initiate(st, elig, tgt, t0, profile)
 
     # 5. fine ticks.  tx_dst is frozen between decisions, so each node's
-    #    outgoing link rate [N] is resolved once per epoch
+    #    outgoing link rate [N] is resolved once per epoch; deliveries move
+    #    through the node axis' one-hot on the dense representation, by
+    #    index on neighbour lists (transfer.progress)
     with phase("channel"):
         rate = rate_to(st["tx_dst"])
     n_ticks = int(round(cfg.decision_period_s / cfg.tick_s))
@@ -372,7 +376,8 @@ def _epoch(st, key, epoch_idx, strategy, cfg: SwarmConfig, profile):
         t_now = t0 + (i.astype(jnp.float32) + 1.0) * cfg.tick_s
         with phase("keys"):
             k = jax.random.fold_in(kt, i)
-        st = _tick(st, k, cfg, profile, rate, st["alive"], t_now)
+        st = _tick(st, k, cfg, profile, rate, st["alive"], t_now,
+                   dense=nbhd.ids is None)
         return st, None
 
     st, _ = jax.lax.scan(tick_body, st, jnp.arange(n_ticks))

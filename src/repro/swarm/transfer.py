@@ -7,6 +7,17 @@ fine ticks *progress* it at the epoch-frozen link capacity and *deliver* it
 into the destination queue — one delivery per receiver per tick, lowest
 origin index winning contention.
 
+Delivery moves the winning origin's fields to its destination, and how
+depends on the epoch's link representation.  Dense, it runs through the
+``[N, N]`` one-hot of each origin's destination (``_deliver_one_hot``):
+masks and reductions that fuse into a few vector ops, where an indexed
+scatter or gather per node runs as a serial loop on the TPU.  Over
+neighbour lists it scatters at the destinations and gathers at the winning
+origins (``_deliver_indexed``), O(N) per tick.  The choice follows the
+representation and not N: the one-hot costs N compares per origin, so the
+serial loop wins only beyond N ≈ 10⁴, and a dense swarm that large
+(``[runs, N, N]`` per epoch) does not fit one chip.
+
 Accounting note: a transfer whose payload has fully arrived
 (``tx_bits <= 0``) but that lost receiver contention stays ``tx_active``
 until it wins a delivery slot.  Those waiting ticks are *queue-wait*, not
@@ -18,6 +29,7 @@ alongside endpoint-down fault stalls.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SwarmConfig
@@ -82,7 +94,64 @@ def initiate(st, elig, tgt, t0, profile):
     return pop_head(st, elig)
 
 
-def progress(st, rate, alive, cfg: SwarmConfig, t_now):
+def _deliver_indexed(dst, arrived):
+    """Contention and the origin-to-destination move by index: scatters
+    over the destination axis and gathers at the winning origin, O(N) per
+    tick — the neighbour-list path, where N reaches 10⁴ and more.
+
+    Returns ``(deliver [N] per origin, dst_mask [N] per destination,
+    read)``, ``read(x)`` moving an origin field ``[..., N]`` to the
+    destinations (origin 0's value where no delivery lands).
+    """
+    n = dst.shape[0]
+    # i32 pin: the origin ranks scatter into i32 contention fields, and
+    # default arange/full are i64 under x64 (swarmlint J002)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    # oob: dst holds node ids from the decision stage, always in [0, N);
+    # drop mode is the .at[] default, never exercised (J003)
+    winner = jnp.full((n,), INT_MAX, jnp.int32).at[dst].min(
+        jnp.where(arrived, rows, INT_MAX))
+    deliver = arrived & (winner[dst] == rows)
+    # oob: in-range dst, see winner scatter above (J003)
+    dst_mask = jnp.zeros((n,), bool).at[dst].max(deliver)
+    # oob: in-range dst, see winner scatter above (J003)
+    inv = jnp.full((n,), 0, jnp.int32).at[dst].max(
+        jnp.where(deliver, rows, 0))                        # origin per dst
+    return deliver, dst_mask, lambda x: x[..., inv]
+
+
+def _arrivals(dst):
+    """``bool [N destinations, N origins]``: ``[j, i]`` where origin ``i``
+    sends to ``j`` — the transfers' one-hot over the node axis."""
+    return jnp.arange(dst.shape[0], dtype=dst.dtype)[:, None] == dst[None, :]
+
+
+def _deliver_one_hot(dst, arrived):
+    """``_deliver_indexed``'s rule and outputs through ``_arrivals``: masks
+    and reductions over an ``[N, N]`` pair of node axes, which fuse, where
+    an indexed scatter or gather per node runs as a serial loop on the
+    TPU — the dense path, which holds ``[N, N]`` per run anyway.
+
+    ``read(x)`` gives ``x`` at each destination's delivering origin bit
+    for bit (``slot_read`` over the origins), and the reduction's
+    identity where no delivery lands; every consumer gates those lanes by
+    ``dst_mask``.
+    """
+    rows = jnp.arange(dst.shape[0], dtype=jnp.int32)
+    # an arrived origin loses to any lower-index arrived origin that has
+    # the same destination: the lowest origin index wins
+    beaten = jnp.any((dst[:, None] == dst[None, :]) & arrived[None, :]
+                     & (rows[None, :] < rows[:, None]), axis=1)
+    deliver = arrived & ~beaten
+    take = _arrivals(dst) & deliver[None, :]   # ≤ 1 origin per destination
+    words = jax.vmap(slot_read, in_axes=(0, None))
+
+    def read(x):
+        return slot_read(x, take) if x.ndim == 1 else words(x, take)
+    return deliver, jnp.any(take, axis=1), read
+
+
+def progress(st, rate, alive, cfg: SwarmConfig, t_now, *, dense: bool):
     """One tick of transfer progress + delivery.
 
     ``rate`` ``[N]`` is each node's epoch-frozen link rate toward its
@@ -90,14 +159,20 @@ def progress(st, rate, alive, cfg: SwarmConfig, t_now):
     — resolved once per epoch, because tx_dst only changes at epoch
     decisions, never mid-tick.  ``alive`` is the epoch fault mask — a
     transfer whose endpoint is down stalls (bits conserved) and resumes
-    when the node recovers.
+    when the node recovers.  ``dense`` is the epoch's link representation
+    (``Neighborhood.ids is None``): it moves deliveries through the node
+    axis' one-hot (``_deliver_one_hot``), neighbour lists by index
+    (``_deliver_indexed``).
     """
     n = st["F"].shape[0]
-    # i32 pin: the origin ranks scatter into i32 contention fields, and
-    # default arange/full are i64 under x64 (swarmlint J002)
     rows = jnp.arange(n, dtype=jnp.int32)
+    dst = st["tx_dst"]
     tick = cfg.tick_s
-    live = alive & alive[st["tx_dst"]]
+    if dense:
+        alive_dst = jnp.any(_arrivals(dst) & alive[:, None], axis=0)
+    else:
+        alive_dst = alive[dst]
+    live = alive & alive_dst
     active = st["tx_active"] & live
     # a fully-arrived payload is off the air: no further bit decrement or
     # transmit-energy accrual while it waits out receiver contention
@@ -117,40 +192,29 @@ def progress(st, rate, alive, cfg: SwarmConfig, t_now):
             st["tx_energy"] = st["tx_energy"] + jnp.where(
                 flying, tx_w * tick, 0.0)
     arrived = active & (st["tx_bits"] <= 0.0)
-    # receiver contention: lowest-index origin wins per destination
-    origin_rank = jnp.where(arrived, rows, INT_MAX)
-    # oob: tx_dst holds node ids from the decision stage, always in
-    # [0, N); drop mode is the .at[] default, never exercised (J003)
-    winner = jnp.full((n,), INT_MAX, jnp.int32).at[st["tx_dst"]].min(
-        jnp.where(arrived, origin_rank, INT_MAX))
-    deliver = arrived & (winner[st["tx_dst"]] == rows)
-
-    # oob: in-range tx_dst, see winner scatter above (J003)
-    dst_mask = jnp.zeros((n,), bool).at[st["tx_dst"]].max(deliver)
-    # scatter in-flight fields to destination rows
-    # oob: in-range tx_dst, see winner scatter above (J003)
-    inv = jnp.full((n,), 0, jnp.int32).at[st["tx_dst"]].max(
-        jnp.where(deliver, rows, 0))                        # origin per dst
-    cum_d = st["tx_cum"][inv]
-    created_d = st["tx_created"][inv]
+    # receiver contention (lowest origin index wins per destination), and
+    # the in-flight fields moved to the destination rows
+    deliver, dst_mask, read = (_deliver_one_hot if dense
+                               else _deliver_indexed)(dst, arrived)
+    cum_d = read(st["tx_cum"])
+    created_d = read(st["tx_created"])
     with phase("visited"):          # the origin's set, plus the origin
-        visited_d = st["tx_visited"][:, inv] | node_bits(
-            inv, st["tx_visited"].shape[0])
+        visited_d = read(st["tx_visited"]
+                         | node_bits(rows, st["tx_visited"].shape[0]))
     profile_d = None
     if "tx_profile" in st:
         with phase("task_profile"):
-            profile_d = st["tx_profile"][inv]
+            profile_d = read(st["tx_profile"])
     if trace_record.hops_enabled(cfg):
         st = trace_record.write_hop_records(
-            st, deliver, seq=st["hop_seq"], src=rows, dst=st["tx_dst"],
+            st, deliver, seq=st["hop_seq"], src=rows, dst=dst,
             t_depart=st["tx_start"], t_arrive=t_now, bits=st["hop_bits"],
             boundary_layer=st["hop_layer"], stall_ticks=st["hop_stall"])
     if trace_record.enabled(cfg):
         st = trace_record.traced_push(
             st, dst_mask, cum_d, created_d, visited_d,
-            src=st["tx_src"][inv], energy=st["tx_energy"][inv],
-            txtime=st["tx_txtime"][inv] + jnp.where(
-                dst_mask, t_now - st["tx_start"][inv], 0.0),
+            src=read(st["tx_src"]), energy=read(st["tx_energy"]),
+            txtime=read(st["tx_txtime"] + (t_now - st["tx_start"])),
             t_now=t_now, cfg=cfg, profile=profile_d)
     else:
         st = push(st, dst_mask, cum_d, created_d, visited_d,

@@ -105,3 +105,28 @@ def test_run_sim_paper_defaults_compiles_with_phi_kernel(one_chip,
 
     c = _compile(fn, one_chip, ((2,), jnp.uint32), ((), I32))
     assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
+def test_only_list_delivery_compiles_to_serial_loops(one_chip, dense):
+    """The TPU compiler lowers a scatter or gather over the batched node
+    axis to a serial loop, a ``kCustom`` fusion.  One tick of transfer
+    progress, vmapped over Table 2's runs: delivery through the dense
+    one-hot has none; over neighbour lists it keeps its scatters."""
+    from repro.swarm.simulator import init_state
+    from repro.swarm.transfer import progress
+    cfg = SwarmConfig()
+    n, runs = cfg.num_workers, cfg.num_runs
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    st = jax.eval_shape(jax.vmap(lambda k: init_state(k, cfg, n)),
+                        jax.random.split(jax.random.PRNGKey(0), runs))
+    fn = jax.vmap(lambda s, r, a: progress(s, r, a, cfg, jnp.float32(0.5),
+                                           dense=dense))
+    c = jax.jit(fn).lower(jax.tree.map(on_chip, st),
+                          on_chip(jax.ShapeDtypeStruct((runs, n), F32)),
+                          on_chip(jax.ShapeDtypeStruct((runs, n), bool))
+                          ).compile()
+    assert ("kind=kCustom" in c.as_text()) != dense

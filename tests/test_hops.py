@@ -188,7 +188,8 @@ def _contention_state(cfg, bits, rate):
     return st, rate, alive
 
 
-def test_contended_delivery_energy_pins_to_single_transfer_value():
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
+def test_contended_delivery_energy_pins_to_single_transfer_value(dense):
     """The loser of receiver contention must stop accruing airtime energy
     once its payload has fully arrived: both tasks cost exactly one tick
     of transmit power, and tx_bits never runs below zero forever."""
@@ -198,11 +199,13 @@ def test_contended_delivery_energy_pins_to_single_transfer_value():
     tx_w = 10.0 ** (cfg.tx_power_dbm / 10.0) * 1e-3
     # both payloads arrive within one tick
     st, rate, alive = _contention_state(cfg, bits=100.0, rate=100.0 / tick)
-    st = transfer_mod.progress(st, rate, alive, cfg, tick)        # tick 1
+    st = transfer_mod.progress(st, rate, alive, cfg, tick,
+                               dense=dense)                       # tick 1
     assert bool(st["tx_active"][1]) and not bool(st["tx_active"][0])
     assert float(jnp.sum(st["e_tx"])) == pytest.approx(2 * tx_w * tick)
     bits_frozen = float(st["tx_bits"][1])
-    st = transfer_mod.progress(st, rate, alive, cfg, 2 * tick)    # tick 2
+    st = transfer_mod.progress(st, rate, alive, cfg, 2 * tick,
+                               dense=dense)                       # tick 2
     assert not bool(st["tx_active"][1])                          # delivered
     # no further accrual for the waiting tick, bits frozen at arrival
     assert float(jnp.sum(st["e_tx"])) == pytest.approx(2 * tx_w * tick)
@@ -220,7 +223,8 @@ def test_contended_delivery_energy_pins_to_single_transfer_value():
     assert np.allclose(hdec["transfer_time_s"], [tick, 2 * tick])
 
 
-def test_avg_transfer_time_uses_delivered_denominator():
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
+def test_avg_transfer_time_uses_delivered_denominator(dense):
     """An in-flight transfer at sim end must not drag the mean down."""
     cfg = dataclasses.replace(SwarmConfig(), num_workers=3)
     profile = make_profile(cfg)
@@ -230,7 +234,7 @@ def test_avg_transfer_time_uses_delivered_denominator():
     # it is still in flight when the sim ends
     st["tx_dst"] = jnp.asarray([2, 0, 0], jnp.int32)
     st["tx_bits"] = jnp.asarray([100.0, 1e12, 0.0], jnp.float32)
-    st = transfer_mod.progress(st, rate, alive, cfg, tick)
+    st = transfer_mod.progress(st, rate, alive, cfg, tick, dense=dense)
     out = {k: float(v) for k, v in sim.summarize(st, cfg, profile).items()}
     assert out["transfers"] == 2.0
     assert out["transfers_delivered"] == 1.0
@@ -239,7 +243,8 @@ def test_avg_transfer_time_uses_delivered_denominator():
     assert out["avg_transfer_time_s"] == pytest.approx(tick)
 
 
-def test_hop_energy_join_reproduces_e_tx():
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
+def test_hop_energy_join_reproduces_e_tx(dense):
     """Per-hop airtime-J attribution joins back to the scalar ``e_tx``
     accumulator exactly once every transfer delivers: both contenders pay
     two flying ticks of transmit power; the loser's extra stalled tick
@@ -251,7 +256,8 @@ def test_hop_energy_join_reproduces_e_tx():
     st, rate, alive = _contention_state(cfg, bits=100.0,
                                        rate=100.0 / (2 * tick))
     for i in range(1, 8):
-        st = transfer_mod.progress(st, rate, alive, cfg, i * tick)
+        st = transfer_mod.progress(st, rate, alive, cfg, i * tick,
+                                   dense=dense)
     assert float(st["tx_delivered"]) == 2.0
     hdec = decode_hops(np.asarray(st["trace_hops"]))
     air = hop_airtime_s(hdec, tick)

@@ -367,3 +367,26 @@ def test_no_indexed_access_to_slot_fields(traced):
         if name == "push":     # the visited words are slot fields too
             assert st["q_visited"].shape not in shapes
             assert st["tx_visited"].shape not in shapes
+
+
+@pytest.mark.parametrize("kind", ["plain", "traced", "mix"])
+def test_dense_delivery_has_no_indexed_access_to_node_fields(kind):
+    """``progress`` on the dense representation moves deliveries through
+    the node axis' one-hot: no gather or scatter reads or writes an
+    ``[N]`` or ``[W, N]`` node field.  On neighbour lists it still
+    scatters at the destinations and gathers at the winning origins."""
+    cfg = _cfg(kind == "traced")
+    if kind == "mix":
+        cfg = dataclasses.replace(cfg, trace_capacity=64,
+                                  task_profiles=("vgg16", "resnet50"),
+                                  task_mix=(0.5, 0.5))
+    st = init_state(jax.random.PRNGKey(0), cfg, N)
+    rate = jnp.ones((N,), jnp.float32)
+    alive = jnp.ones((N,), bool)
+    node_fields = {(N,), st["tx_visited"].shape}
+    for dense in (True, False):
+        shapes = indexed_operand_shapes(
+            lambda s: transfer.progress(s, rate, alive, cfg,
+                                        jnp.float32(1.0), dense=dense), st)
+        assert node_fields & set(shapes) == (set() if dense
+                                             else node_fields), dense

@@ -128,7 +128,8 @@ def _state_with_heads(cfg, profile_ids, cum):
     return st
 
 
-def test_offloaded_task_keeps_its_profile():
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
+def test_offloaded_task_keeps_its_profile(dense):
     cfg = dataclasses.replace(MIX, num_workers=4)
     mix = make_profile(cfg)
     assert isinstance(mix, ProfileMix)
@@ -148,7 +149,8 @@ def test_offloaded_task_keeps_its_profile():
     assert float(st["tx_cum"][1]) == float(mix.cum_gflops[0, 1])
     st["tx_bits"] = jnp.where(elig, 0.0, st["tx_bits"])
     rate = jnp.full((4,), 1e9, jnp.float32)
-    st = transfer.progress(st, rate, jnp.ones((4,), bool), cfg, 0.01)
+    st = transfer.progress(st, rate, jnp.ones((4,), bool), cfg, 0.01,
+                           dense=dense)
     assert st["q_active"][3, :3].tolist() == [True, True, False]
     assert st["q_profile"][3, :2].tolist() == [0, 1]
     assert float(st["q_cum"][3, 1]) == float(mix.cum_gflops[1, 1])

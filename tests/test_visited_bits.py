@@ -247,10 +247,11 @@ def ref_deliveries(tx_active, tx_bits, tx_dst):
     return won
 
 
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "lists"])
 @pytest.mark.parametrize("traced", [False, True])
 @pytest.mark.parametrize("fill", ["random", "full", "empty"])
 @pytest.mark.parametrize("n", STATE_NS)
-def test_progress_marks_origin(n, fill, traced):
+def test_progress_marks_origin(n, fill, traced, dense):
     st, cfg, rng, q_bool, tx_bool = _state(n, 7, fill, traced=traced)
     tx_active = rng.random(n) < 0.7
     tx_bits = np.where(rng.random(n) < 0.7, 0.0, 1e9).astype(np.float32)
@@ -259,7 +260,7 @@ def test_progress_marks_origin(n, fill, traced):
               tx_dst=jnp.asarray(tx_dst))
     rate = jnp.zeros((n,), jnp.float32)        # nothing in flight moves
     got = transfer.progress(st, rate, jnp.ones((n,), bool), cfg,
-                            jnp.float32(1.0))
+                            jnp.float32(1.0), dense=dense)
     won = ref_deliveries(tx_active, tx_bits, tx_dst)
     assert won
     mask = np.zeros(n, bool)
@@ -351,7 +352,7 @@ def test_vmap_over_runs_matches_bool():
                                jnp.zeros((n,), jnp.int32), jnp.float32(0.0),
                                profile)
         return mid, transfer.progress(st, rate, jnp.ones((n,), bool), cfg,
-                                      jnp.float32(1.0))
+                                      jnp.float32(1.0), dense=True)
 
     mid, got = jax.jit(jax.vmap(one))(batch, jnp.asarray(mask), vis)
     for r, (st, _, _, q_bool, tx_bool) in enumerate(runs):
